@@ -48,7 +48,7 @@ def main(n: int = 1000, d: int = 4096, Q: int = 6, k: int = 5):
     qidx = rng.integers(0, n, Q)
     queries = corpus[qidx] + 0.02 * rng.normal(size=(Q, d)).astype(np.float32)
     both = jnp.concatenate([jnp.asarray(corpus), jnp.asarray(queries)], 0)
-    rot, _ = hadamard_rotate(both, jax.random.PRNGKey(0), use_kernel="ref")
+    rot, _ = hadamard_rotate(both, jax.random.PRNGKey(0))
     rot = np.asarray(rot)
     r_before = tail_ratio(corpus)
     r_after = tail_ratio(rot[:n])

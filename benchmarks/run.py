@@ -15,6 +15,8 @@ BENCHES = ["fig2", "fig3a", "fig4a", "fig4b", "fig5", "fig6", "fig7",
 
 
 def main() -> None:
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     want = sys.argv[1:] or BENCHES
     print("name,us_per_call,derived")
     for name in want:

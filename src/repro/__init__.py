@@ -3,14 +3,5 @@ built as a multi-pod JAX training/serving framework. See README.md."""
 
 __version__ = "0.1.0"
 
-try:
-    from repro import _compat
-except ModuleNotFoundError as _e:  # pragma: no cover - jax-free tooling
-    # repro.analysis and tools/repro_lint.py are pure stdlib by design:
-    # the CI lint job runs them without jax installed. Anything that
-    # actually touches arrays still fails loudly at its own import.
-    if _e.name not in ("jax", "jaxlib"):
-        raise
-else:
-    _compat.install()
-    del _compat
+# Nothing here imports jax: repro.analysis and tools/repro_lint.py are pure
+# stdlib by design, and the CI lint job runs them without jax installed.

@@ -39,6 +39,10 @@ DIM_BOUNDS: Dict[str, int] = {
     "block": 4096,      # feature-block width, lane-aligned
     "d_pad": 65536,     # padded feature dim ceiling
     "n_buf": 8,         # streaming slot depth
+    "rows": 32,         # sublanes of one HBM tile (8 fp32 … 32 int8)
+    "width": 4096,      # lane-aligned fetch width, max(block, 128)
+    "chunk": 2048,      # (query, arm) pairs per pull launch
+    "n_out": 16,        # pull output rows; n_out·chunk ≤ 32768 words
 }
 
 _WORST_CASE_ITEMSIZE = 4   # f32/i32; bf16 kernels only ever cost less
@@ -49,6 +53,8 @@ _LANE = 128
 def _dim_value(node: ast.AST) -> Optional[int]:
     """Concrete or bounded value of one block-shape dim, None when
     unpriceable."""
+    if isinstance(node, ast.Constant) and node.value is None:
+        return 1                    # squeezed dim: one element per block
     if isinstance(node, ast.Constant) and isinstance(node.value, int):
         return node.value
     if isinstance(node, ast.Name):

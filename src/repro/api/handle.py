@@ -110,7 +110,7 @@ class Index:
     @classmethod
     def build(cls, corpus, cfg, rng=None, *, shards: int = 1,
               placement: str = "round_robin", capacity: Optional[int] = None,
-              impl: str = "auto", payload=None,
+              payload=None,
               cache: Optional[CachePolicy] = None,
               compaction: Optional[CompactionPolicy] = None) -> "Index":
         """Preprocess ``corpus`` (n, d) into a served index. ``shards > 1``
@@ -121,10 +121,9 @@ class Index:
         if shards > 1:
             store, gids = build_sharded_index(
                 np.asarray(corpus), cfg, rng, shards=shards,
-                placement=placement, capacity=capacity, impl=impl)
+                placement=placement, capacity=capacity)
         else:
-            store = build_index(corpus, cfg, rng, capacity=capacity,
-                                impl=impl)
+            store = build_index(corpus, cfg, rng, capacity=capacity)
             gids = np.arange(store.n_live, dtype=np.int64)
         handle = cls(store, build_gids=gids, cache=cache,
                      compaction=compaction)

@@ -24,8 +24,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, List, Optional
 
+from repro.kernels import IMPLS
+
 MODES = ("auto", "fused", "rounds")
-IMPLS = ("auto", "pallas", "ref", "xla")
 CACHE_POLICIES = ("use", "bypass", "refresh")
 
 #: schema version of KNNResult / ServeStats.as_dict() — bump on any field
@@ -60,7 +61,7 @@ class QuerySpec:
 
     k: Optional[int] = None            # top-k override (None = store cfg.k)
     mode: str = "auto"                 # auto | fused | rounds driver
-    impl: str = "auto"                 # kernel impl (auto/pallas/ref/xla)
+    impl: str = "auto"                 # kernel impl (IMPLS; see kernels/ops)
     delta: Optional[float] = None      # failure-probability override
     max_rounds: Optional[int] = None   # pull-budget cap (racing rounds)
     eliminate: bool = True             # Alg. 1 elimination on/off

@@ -54,7 +54,7 @@ def _dense_exact_fn(ds: DenseDataset, q: jax.Array, cfg: BMOConfig, impl: str):
     def exact(arm_idx):
         rows = ds.x[arm_idx]                       # (B, d_pad)
         dist = kops.pairwise_dist(q[None], rows, metric=cfg.metric, impl=impl)
-        return dist[0] / ds.d                       # θ units
+        return dist[0] / ds.d_pad   # θ units: the mean a block pull estimates
 
     return exact
 
@@ -192,7 +192,7 @@ def knn(corpus, queries, cfg: BMOConfig, rng: jax.Array, *,
     if cfg.rotate:
         assert cfg.metric == "l2", "rotation preserves only ℓ2"
         rng, sub = jax.random.split(rng)
-        both, _ = hadamard_rotate(jnp.concatenate([x, qs], 0), sub, use_kernel=impl)
+        both, _ = hadamard_rotate(jnp.concatenate([x, qs], 0), sub)
         x, qs = both[: x.shape[0]], both[x.shape[0]:]
     ds = DenseDataset.build(x, block=cfg.block)
     qs = ds.pad_query(qs)

@@ -19,7 +19,7 @@ class DenseDataset:
     """Corpus (n, d), padded so d is a multiple of the sampling block."""
 
     x: jax.Array               # (n, d_pad) float32
-    d: int                     # true dimension (θ normalizer)
+    d: int                     # true dimension (exact-eval cost)
     block: int                 # sampling block width
 
     @property
@@ -91,7 +91,7 @@ class SparseDataset:
                    nnz=jnp.asarray(nnz, jnp.int32), d=d)
 
 
-def hadamard_rotate(x: jax.Array, rng: jax.Array, *, use_kernel: str = "auto"):
+def hadamard_rotate(x: jax.Array, rng: jax.Array):
     """§IV-B: x' = H D x per row (D = random ±1 diag, H = normalized FWHT).
     Pads d to the next power of two (paper: 'zero padding'). Preserves
     pairwise ℓ2 distances up to the common padding. Returns (x', signs)."""
@@ -101,4 +101,4 @@ def hadamard_rotate(x: jax.Array, rng: jax.Array, *, use_kernel: str = "auto"):
     if dp != d:
         x = jnp.pad(x, ((0, 0), (0, dp - d)))
     signs = jax.random.rademacher(rng, (dp,), jnp.float32)
-    return kops.fwht(x * signs[None, :], impl=use_kernel), signs
+    return kops.fwht(x * signs[None, :]), signs

@@ -57,7 +57,7 @@ def _local_knn(x_loc, qs_loc, rng, *, cfg: BMOConfig, d: int, n_loc: int,
                            prior_weight=0.0, model_axis="model")
     # exact-evaluate the certified local top-k so the merge compares exact
     # θ values (partial over the model axis → psum), then gather + reduce
-    part = _dense_exact_theta(x_loc, qs_loc, res.indices, cfg.metric, d)
+    part = _dense_exact_theta(x_loc, qs_loc, res.indices, cfg.metric)
     vals = guard_local_topk(res.indices, jax.lax.psum(part, "model"), alive)
     topk_g = res.indices.astype(jnp.int32) + shard * n_loc
     merged_idx, merged_vals = merge_local_topk(vals, topk_g, dp_axes, cfg.k)

@@ -38,6 +38,8 @@ def lm_batch(vocab: int, batch: int, seq: int, *, seed: int, step: int,
 # kNN corpora
 # ---------------------------------------------------------------------------
 
+_ROWS = 4096   # rows per draw of the dense generator
+
 
 def clustered_dense(n: int, d: int, *, n_clusters: int = 64,
                     noise: float = 0.15, heavy_tail: float = 1.0,
@@ -49,8 +51,14 @@ def clustered_dense(n: int, d: int, *, n_clusters: int = 64,
     centers = rng.normal(size=(n_clusters, d)).astype(np.float32)
     assign = rng.integers(0, n_clusters, n)
     scale = (1.0 + heavy_tail * rng.exponential(1.0, size=(n, 1))).astype(np.float32)
-    pts = centers[assign] + noise * scale * rng.normal(size=(n, d)).astype(np.float32)
-    return pts.astype(np.float32)
+    # drawn in row chunks — the same stream as one (n, d) draw, without its
+    # float64 temporaries at paper scale (100k × 12288)
+    pts = np.empty((n, d), np.float32)
+    for s in range(0, n, _ROWS):
+        e = min(n, s + _ROWS)
+        pts[s:e] = centers[assign[s:e]] + noise * scale[s:e] * rng.normal(
+            size=(e - s, d)).astype(np.float32)
+    return pts
 
 
 def clustered_sparse(n: int, d: int, *, sparsity: float = 0.07,

@@ -151,7 +151,7 @@ def _exactify_frontier(x, qs, st: FrontierState, *, k: int, metric: str,
     slots = jnp.where(need, jnp.take_along_axis(st.ids, pos, axis=1), 0)
     vals = jax.lax.cond(
         jnp.any(need),
-        lambda s: _dense_exact_theta(x, qs, s, metric, d),
+        lambda s: _dense_exact_theta(x, qs, s, metric),
         lambda s: jnp.zeros(s.shape, jnp.float32), slots)
     cur = jnp.take_along_axis(st.mean, pos, axis=1)
     mean = st.mean.at[qi, pos].set(jnp.where(need, vals, cur))
@@ -257,7 +257,7 @@ _BR_SPEC = BatchedRaceState(*([P(AXIS)] * len(BatchedRaceState._fields)))
 def _sharded_fused_partial_fn(mesh, cfg, d, log_term, prior_weight, stride):
     def body(x, qs, st, pool):
         st = _squeeze(st)
-        st = _exactify_frontier(x[0], qs, st, k=cfg.k, metric=cfg.metric,
+        st = _exactify_frontier(x, qs, st, k=cfg.k, metric=cfg.metric,
                                 d=d)
         ci = _frontier_ci(st, cfg, log_term, pool[0], prior_weight)
         gids = jax.lax.axis_index(AXIS) * stride + st.ids
@@ -677,7 +677,7 @@ class ShardedFusedSession(RaceSession):
             prior_st = dev["prior_var"]
         self._impl, self._eliminate = impl, eliminate
         self._prior_weight = prior_weight
-        nb = self._x_st.shape[2] // store.block
+        nb = self._x_st.shape[1] // store.block
         P_ = cfg.pulls_per_round
         self._log_term = float(np.log(
             2.0 / conf.delta_prime(cfg.delta, self._S * self._stride, nb)))

@@ -289,16 +289,28 @@ def batched_race_topk(
 # ---------------------------------------------------------------------------
 
 
-def _dense_exact_theta(x, qs, sel, metric: str, d: int):
-    """Exact θ for selected slots: full-row distance / d (the Alg. 1 lazy
-    exact evaluation both dense drivers share). sel (Q, B) → (Q, B)."""
+def draw_blocks(key: jax.Array, shape, nb: int) -> jax.Array:
+    """Uniform block indices in [0, nb) of ``shape``. Drawn flat and then
+    reshaped (the same values as a shaped draw): the TPU lays a shaped
+    draw out with its short trailing pull axis padded to 128 lanes, which
+    at the wide init's (Q, capacity, T0) is gigabytes of HBM."""
+    return jax.random.randint(key, (math.prod(shape),), 0, nb).reshape(shape)
+
+
+def _dense_exact_theta(x, qs, sel, metric: str):
+    """Exact θ for selected slots (the Alg. 1 lazy exact evaluation both
+    dense drivers share). sel (Q, B) → (Q, B). The distance is divided by
+    the store's padded width d_pad, the mean a block pull estimates: the
+    Hadamard rotation spreads a row over all d_pad coordinates, so dividing
+    by the unpadded d would put exact arms on a scale d_pad/d above the
+    sampled ones and misorder them."""
     rows = x[sel]                                            # (Q, B, d_pad)
     diff = rows - qs[:, None, :]
     if metric == "l1":
         dist = jnp.sum(jnp.abs(diff), -1)
     else:
         dist = jnp.sum(diff * diff, -1)
-    return dist / d
+    return dist / x.shape[-1]
 
 
 def _frontier_ci(st: FrontierState, cfg: BMOConfig, log_term: float,
@@ -347,7 +359,7 @@ def _fused_init(x, qs, alive, prior_var, rng, *, cfg: BMOConfig, block: int,
 
     rng, sub = jax.random.split(rng)
     all_arms = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None], (Q, n))
-    blk = jax.random.randint(sub, (Q, n, T0), 0, nb)
+    blk = draw_blocks(sub, (Q, n, T0), nb)
     with jax.named_scope("repro.fused_epoch_pull"):
         stats = kops.fused_epoch_pull(x, qs, all_arms, blk, block=block,
                                       metric=cfg.metric, impl=impl,
@@ -404,7 +416,7 @@ def _fused_epoch_step(x, qs, st: FrontierState, prior_pool, *,
 
     # ---- one fused launch: T pulls per selected arm, reduced on-chip -----
     rng, sub = jax.random.split(st.rng)
-    blk = jax.random.randint(sub, (Q, B, T), 0, nb)
+    blk = draw_blocks(sub, (Q, B, T), nb)
     with jax.named_scope("repro.fused_epoch_pull"):
         stats = kops.fused_epoch_pull(x, qs, slot_safe, blk, block=block,
                                       metric=cfg.metric, impl=impl,
@@ -422,7 +434,7 @@ def _fused_epoch_step(x, qs, st: FrontierState, prior_pool, *,
                & ~jnp.take_along_axis(st.exact, sel, axis=1))
     exact_vals = jax.lax.cond(
         jnp.any(crossed),
-        lambda s: _dense_exact_theta(x, qs, s, cfg.metric, d),
+        lambda s: _dense_exact_theta(x, qs, s, cfg.metric),
         lambda s: jnp.zeros((Q, B), jnp.float32), slot_safe)
     nm = jnp.where(crossed, exact_vals, nm)
     mean = st.mean.at[qi, sel].set(nm)
@@ -577,13 +589,13 @@ def _dense_index_knn(x, qs, alive, prior_var, rng, *, cfg: BMOConfig,
     nb = d_pad // block
 
     def pull(sel, key):
-        blk = jax.random.randint(key, sel.shape + (cfg.pulls_per_round,), 0, nb)
+        blk = draw_blocks(key, sel.shape + (cfg.pulls_per_round,), nb)
         with jax.named_scope("repro.block_pull_multi"):
             return kops.block_pull_multi(x, qs, sel, blk, block=block,
                                          metric=cfg.metric, impl=impl)
 
     def exact(sel):
-        return _dense_exact_theta(x, qs, sel, cfg.metric, d)
+        return _dense_exact_theta(x, qs, sel, cfg.metric)
 
     return batched_race_topk(
         pull, exact, n=n, Q=Q,

@@ -16,7 +16,7 @@ directory is bit-compatible with the training checkpoints' tooling.
 """
 from __future__ import annotations
 
-import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -29,6 +29,10 @@ from repro.index.store import IndexStore
 from repro.utils import get_logger
 
 log = get_logger("repro.index")
+
+#: corpus rows laid out per build step: the build's transient device memory
+#: is a few such chunks on top of the capacity-padded store
+BUILD_ROWS = 4096
 
 
 def _row_block_stats(x: jax.Array, block: int, metric: str):
@@ -54,45 +58,69 @@ def _sparse_prior(values: jax.Array, nnz: jax.Array, d: int):
     return var * scale
 
 
+def prepare_rows(rows: jax.Array, d_pad: int, signs: Optional[jax.Array],
+                 block: int, metric: str):
+    """Dense (B, d) rows → the store's blocked (B, d_pad) layout, rotated
+    with the cached ``signs`` in the rotated box, and their block-statistics
+    priors (B,). Shared by the build and by online inserts (mutable.py)."""
+    x = jnp.asarray(rows, jnp.float32)
+    pad = d_pad - x.shape[1]
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)))
+    if signs is not None:
+        from repro.kernels import ops as kops
+        x = kops.fwht(x * signs[None, :])
+    return x, _row_block_stats(x, block, metric)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "metric"),
+                   donate_argnums=(0, 1))
+def _write_rows(x, prior_var, rows, signs, start, *, block: int, metric: str):
+    """Lay out one row chunk into the (donated) padded store in place."""
+    xr, pv = prepare_rows(rows, x.shape[1], signs, block, metric)
+    return (jax.lax.dynamic_update_slice(x, xr, (start, 0)),
+            jax.lax.dynamic_update_slice(prior_var, pv, (start,)))
+
+
 def build_index(corpus, cfg: BMOConfig, rng: jax.Array, *,
-                capacity: Optional[int] = None,
-                impl: str = "auto") -> IndexStore:
+                capacity: Optional[int] = None) -> IndexStore:
     """Preprocess ``corpus`` into an IndexStore ready for batched serving.
 
     corpus: (n, d) array (dense; also the input for the rotated/sparse boxes
     — ``cfg.rotate`` / ``cfg.sparse`` select the §IV box exactly like
     ``bmo_nn.knn``). ``capacity``: total slots (≥ n); defaults to the next
     power of two so early inserts don't force a growth.
+
+    Dense/rotated rows are moved to the device and laid out ``BUILD_ROWS``
+    at a time into the preallocated store, so a host-side (numpy) corpus is
+    never resident on the device in full beside it.
     """
     if cfg.sparse:
         return _build_sparse(corpus, cfg, capacity)
-    x = jnp.asarray(corpus, jnp.float32)
-    n, d = x.shape
+    if not hasattr(corpus, "shape"):
+        corpus = np.asarray(corpus, np.float32)
+    n, d = corpus.shape
     kind = "rotated" if cfg.rotate else "dense"
     signs = None
+    d_pad = d + (-d) % cfg.block
     if cfg.rotate:
         assert cfg.metric == "l2", "rotation preserves only ℓ2"
         assert cfg.block & (cfg.block - 1) == 0, \
             "rotated box needs a power-of-two block"
-        from repro.kernels import ops as kops
-        dp = max(next_pow2(d), cfg.block)
-        x = jnp.pad(x, ((0, 0), (0, dp - d)))
-        signs = jax.random.rademacher(rng, (dp,), jnp.float32)
-        x = kops.fwht(x * signs[None, :], impl=impl)
-    # blocked layout
-    pad = (-x.shape[1]) % cfg.block
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-        if signs is not None:  # keep signs aligned with d_pad for queries
-            signs = jnp.pad(signs, (0, pad), constant_values=1.0)
+        d_pad = max(next_pow2(d), cfg.block)
+        signs = jax.random.rademacher(rng, (d_pad,), jnp.float32)
     cap = capacity or next_pow2(n)
     assert cap >= n
-    if cap > n:
-        x = jnp.pad(x, ((0, cap - n), (0, 0)))
+    x = jnp.zeros((cap, d_pad), jnp.float32)
+    prior_var = jnp.zeros((cap,), jnp.float32)
+    for start in range(0, n, BUILD_ROWS):
+        rows = jnp.asarray(corpus[start:start + BUILD_ROWS], jnp.float32)
+        x, prior_var = _write_rows(x, prior_var, rows, signs,
+                                   jnp.int32(start), block=cfg.block,
+                                   metric=cfg.metric)
     alive = jnp.arange(cap) < n
-    prior_var = _row_block_stats(x, cfg.block, cfg.metric)
     log.info("built %s index: n=%d cap=%d d=%d d_pad=%d block=%d",
-             kind, n, cap, d, x.shape[1], cfg.block)
+             kind, n, cap, d, d_pad, cfg.block)
     return IndexStore(kind=kind, cfg=cfg, d=d, alive=alive, x=x,
                       block=cfg.block, signs=signs, prior_var=prior_var)
 

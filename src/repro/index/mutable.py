@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.datasets import next_pow2
-from repro.index.builder import _row_block_stats, _sparse_prior
+from repro.index.builder import _sparse_prior, prepare_rows
 from repro.index.store import IndexStore, free_slots
 from repro.utils import get_logger
 
@@ -83,16 +83,11 @@ def insert(store: IndexStore, rows) -> Tuple[IndexStore, np.ndarray]:
                                    values=values, nnz=nnz_arr,
                                    prior_var=prior), slots
 
-    x_rows = jnp.asarray(rows)
-    pad = store.d_pad - x_rows.shape[1]
-    if pad:
-        x_rows = jnp.pad(x_rows, ((0, 0), (0, pad)))
-    if store.kind == "rotated":
-        from repro.kernels import ops as kops
-        x_rows = kops.fwht(x_rows * store.signs[None, :])
+    x_rows, row_prior = prepare_rows(
+        rows, store.d_pad, store.signs if store.kind == "rotated" else None,
+        store.block, store.cfg.metric)
     x = store.x.at[sl].set(x_rows)
-    prior = store.prior_var.at[sl].set(
-        _row_block_stats(x_rows, store.block, store.cfg.metric))
+    prior = store.prior_var.at[sl].set(row_prior)
     return dataclasses.replace(store, alive=alive, x=x, prior_var=prior), slots
 
 

@@ -62,6 +62,7 @@ from repro.core.bmo_nn import sparse_exact_theta
 from repro.core.datasets import SparseDataset, next_pow2
 from repro.index import placement as plc
 from repro.index.batched_race import (_dense_exact_theta, _frontier_ci,
+                                      draw_blocks,
                                       _fused_epoch_step, _fused_init,
                                       _sparse_index_knn, batched_race_topk)
 from repro.index.builder import build_index
@@ -167,16 +168,31 @@ class ShardedIndexStore:
         return self._mesh
 
     def device_arrays(self) -> dict:
-        """Shard-stacked arrays, placed P("shards") on the mesh (cached per
-        instance; mutations build new instances so this self-invalidates)."""
+        """Per-shard arrays assembled into mesh-sharded global arrays, each
+        shard's piece on its own device (cached per instance; mutations
+        build new instances so this self-invalidates). The dense corpus is
+        concatenated, (S·stride, d_pad) P("shards"): every device's piece
+        IS its shard's ``x``, so the corpus is never copied or gathered on
+        one device. The small per-slot arrays are stacked, (S, …)."""
         if "_dev" not in self.__dict__:
             sh = NamedSharding(self.mesh, P(AXIS))
+            devs = list(self.mesh.devices.flat)
+
+            def assemble(name, stack):
+                parts = [jax.device_put(getattr(s, name), dv)
+                         for s, dv in zip(self.shards, devs)]
+                if stack:
+                    parts = [p[None] for p in parts]
+                shape = ((len(parts) * parts[0].shape[0],)
+                         + parts[0].shape[1:])
+                return jax.make_array_from_single_device_arrays(
+                    shape, sh, parts)
+
             names = (("indices", "values", "nnz") if self.kind == "sparse"
-                     else ("x",)) + ("alive", "prior_var")
-            self._dev = {
-                name: jax.device_put(
-                    jnp.stack([getattr(s, name) for s in self.shards]), sh)
-                for name in names}
+                     else ()) + ("alive", "prior_var")
+            self._dev = {name: assemble(name, True) for name in names}
+            if self.kind != "sparse":
+                self._dev["x"] = assemble("x", False)
         return self._dev
 
     def prepare_queries(self, queries) -> jax.Array:
@@ -193,7 +209,7 @@ class ShardedIndexStore:
 
 def build_sharded_index(corpus, cfg: BMOConfig, rng: jax.Array, *,
                         shards: int, placement: str = "round_robin",
-                        capacity: Optional[int] = None, impl: str = "auto",
+                        capacity: Optional[int] = None,
                         ) -> Tuple[ShardedIndexStore, np.ndarray]:
     """Partition ``corpus`` (n, d) across ``shards`` per-shard IndexStores.
     Returns ``(store, global_ids)`` with ``global_ids[i]`` the global slot of
@@ -202,7 +218,10 @@ def build_sharded_index(corpus, cfg: BMOConfig, rng: jax.Array, *,
 
     All shards share one rotation: ``build_index`` draws the §IV-B sign
     vector from ``rng`` alone, so passing the *same* key to every shard
-    build caches the same rotation everywhere (queries are rotated once)."""
+    build caches the same rotation everywhere (queries are rotated once).
+    When the process sees at least ``shards`` devices, shard s is built on
+    device s — the device the mesh places it on — so no device ever holds
+    more than its own shard."""
     corpus = np.asarray(corpus)
     n = corpus.shape[0]
     if shards < 1:
@@ -211,8 +230,12 @@ def build_sharded_index(corpus, cfg: BMOConfig, rng: jax.Array, *,
     rows_of = [np.nonzero(sid == s)[0] for s in range(shards)]
     per_cap = (capacity // shards if capacity
                else next_pow2(max(1, max(len(r) for r in rows_of))))
-    stores = [build_index(corpus[rows], cfg, rng, capacity=per_cap, impl=impl)
-              for rows in rows_of]
+    devs = jax.devices()
+    stores = []
+    for s, rows in enumerate(rows_of):
+        with jax.default_device(devs[s] if shards <= len(devs) else None):
+            stores.append(build_index(corpus[rows], cfg, rng,
+                                      capacity=per_cap))
     if cfg.sparse:                     # uniform padded-CSR width across shards
         m_max = max(s.m for s in stores)
         stores = [_widen_sparse(s, m_max) for s in stores]
@@ -511,7 +534,7 @@ def local_dense_race(x_loc, qs, alive, prior, rng, *, cfg: BMOConfig,
     def pull(sel, key):
         if model_axis is not None:
             key = jax.random.fold_in(key, jax.lax.axis_index(model_axis))
-        blk = jax.random.randint(key, sel.shape + (P_,), 0, nb_loc)
+        blk = draw_blocks(key, sel.shape + (P_,), nb_loc)
         vals = kops.block_pull_multi(x_loc, qs, sel, blk, block=block,
                                      metric=cfg.metric, impl=impl)
         if model_axis is not None:
@@ -519,7 +542,7 @@ def local_dense_race(x_loc, qs, alive, prior, rng, *, cfg: BMOConfig,
         return vals
 
     def exact(sel):
-        th = _dense_exact_theta(x_loc, qs, sel, cfg.metric, d)
+        th = _dense_exact_theta(x_loc, qs, sel, cfg.metric)
         if model_axis is not None:
             th = jax.lax.psum(th, model_axis)
         return th
@@ -565,12 +588,12 @@ _OUT_SPECS = (P(), P(), P(), P(), P(), P(AXIS), P(AXIS))
 def _rounds_dense_fn(mesh, cfg, block, d, impl, eliminate, prior_weight,
                      stride):
     def body(x, qs, alive, prior, rng):
-        x, alive, prior = x[0], alive[0], prior[0]
+        alive, prior = alive[0], prior[0]
         rng = jax.random.fold_in(rng, jax.lax.axis_index(AXIS))
         res = local_dense_race(x, qs, alive, prior, rng, cfg=cfg, block=block,
                                d=d, impl=impl, eliminate=eliminate,
                                prior_weight=prior_weight)
-        exact_vals = _dense_exact_theta(x, qs, res.indices, cfg.metric, d)
+        exact_vals = _dense_exact_theta(x, qs, res.indices, cfg.metric)
         vals = guard_local_topk(res.indices, exact_vals, alive)
         gids = jax.lax.axis_index(AXIS) * stride + res.indices
         coord_ops = res.coord_ops + float(cfg.k * d)
@@ -618,7 +641,7 @@ _ST_SPEC = FrontierState(*([P(AXIS)] * len(FrontierState._fields)))
 def _fused_init_fn(mesh, cfg, block, impl, prior_weight):
     def body(x, qs, alive, prior, rng):
         rng = jax.random.fold_in(rng, jax.lax.axis_index(AXIS))
-        st, pool = _fused_init(x[0], qs, alive[0], prior[0], rng, cfg=cfg,
+        st, pool = _fused_init(x, qs, alive[0], prior[0], rng, cfg=cfg,
                                block=block, impl=impl,
                                prior_weight=prior_weight)
         return _unsqueeze(st), pool[None]
@@ -633,7 +656,7 @@ def _fused_step_fn(mesh, cfg, block, d, impl, eliminate, prior_weight,
                    log_term, T):
     def body(x, qs, st, pool):
         st2, n_surv, done = _fused_epoch_step(
-            x[0], qs, _squeeze(st), pool[0], cfg=cfg, block=block, d=d,
+            x, qs, _squeeze(st), pool[0], cfg=cfg, block=block, d=d,
             impl=impl, eliminate=eliminate, prior_weight=prior_weight,
             log_term=log_term, T=T)
         return _unsqueeze(st2), n_surv[None], done[None]
@@ -659,7 +682,7 @@ def _fused_finalize_fn(mesh, cfg, log_term, prior_weight, stride, block, d,
                                     st.mean - ci))
         _, pos = jax.lax.top_k(-score, k)                     # (Q, k)
         slots = jnp.take_along_axis(st.ids, pos, axis=1)
-        vals = _dense_exact_theta(x[0], qs, slots, metric, d)
+        vals = _dense_exact_theta(x, qs, slots, metric)
         ok = jnp.take_along_axis(score, pos, axis=1) < INF
         vals = jnp.where(ok, vals, INF)
         gids = jax.lax.axis_index(AXIS) * stride + slots
@@ -693,7 +716,7 @@ def _sharded_fused_race(store: ShardedIndexStore, qs, prior_st, rng, *,
     block = store.block
     Q = qs.shape[0]
     k = cfg.k
-    nb = x_st.shape[2] // block
+    nb = x_st.shape[1] // block
     P_ = cfg.pulls_per_round
     # δ′ at the GLOBAL slot count — identical per-arm budget to the
     # single-shard fused driver over the same corpus

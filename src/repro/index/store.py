@@ -32,7 +32,8 @@ KINDS = ("dense", "rotated", "sparse")
 class IndexStore:
     kind: str                         # dense | rotated | sparse
     cfg: BMOConfig                    # racing defaults bound at build time
-    d: int                            # true dimension (θ normalizer)
+    d: int                            # true dimension (sparse θ normalizer;
+                                      # dense θ is per d_pad coordinate)
     alive: jax.Array                  # (cap,) bool — tombstone mask
     # --- dense / rotated layout ---
     x: Optional[jax.Array] = None     # (cap, d_pad) float32, blocked layout

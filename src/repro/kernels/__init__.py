@@ -1,3 +1,6 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the race's hot loop (block pulls, the exact scan) and
+their jit'd dispatchers (ops.py) and jnp oracles (ref.py)."""
+
+#: kernel implementations a caller may ask for (``QuerySpec.impl`` and every
+#: ``repro.kernels.ops`` dispatcher take exactly these)
+IMPLS = ("auto", "kernel", "interpret", "ref")

@@ -23,10 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
@@ -102,6 +99,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")) if pltpu else None,
+                                 "arbitrary")),
         interpret=interpret,
     )(q, k, v)

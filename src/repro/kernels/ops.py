@@ -1,12 +1,15 @@
 """Jit'd dispatch wrappers around the Pallas kernels.
 
-``impl``:
-  * "auto"      — Pallas-compiled on TPU, jnp reference on CPU (XLA-fused;
+``impl`` (``IMPLS``):
+  * "auto"      — Pallas-compiled on TPU, jnp reference elsewhere (XLA-fused;
                   the interpreter would be orders of magnitude slower),
   * "kernel"    — Pallas compiled (real TPU lowering),
   * "interpret" — Pallas interpret mode (CPU-executable kernel body; what the
                   kernel sweep tests use against the refs),
   * "ref"       — pure-jnp oracle.
+
+The Hadamard rotation (``fwht``) has one XLA implementation on every
+backend and takes no ``impl``.
 """
 from __future__ import annotations
 
@@ -14,36 +17,53 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.kernels import IMPLS
 from repro.kernels import ref as kref
-from repro.kernels.block_pull import block_pull_multi_pallas, block_pull_pallas
-from repro.kernels.fused_race import fused_epoch_pull_pallas
-from repro.kernels.fwht import fwht_pallas
+from repro.kernels.block_pull import pull_pallas
 from repro.kernels.pairwise_dist import pairwise_dist_pallas
 
 
 def _resolve(impl: str) -> str:
+    if impl not in IMPLS:
+        raise ValueError(f"unknown impl {impl!r} (want one of {IMPLS})")
     if impl != "auto":
         return impl
     return "kernel" if jax.default_backend() == "tpu" else "ref"
 
 
-@functools.partial(jax.jit, static_argnames=("impl",))
-def fwht(x: jax.Array, impl: str = "auto") -> jax.Array:
-    impl = _resolve(impl)
-    if impl == "ref":
-        return kref.fwht_ref(x)
-    return fwht_pallas(x, interpret=(impl == "interpret"))
+@jax.jit
+def fwht(x: jax.Array) -> jax.Array:
+    """Normalized fast Walsh–Hadamard transform along the last axis, the
+    §IV-B rotation. x (..., d), d a power of two. Decimation-in-frequency
+    butterfly, accumulated in fp32."""
+    d = x.shape[-1]
+    assert d & (d - 1) == 0, f"d={d} not a power of two"
+    orig_shape = x.shape
+    orig_dtype = x.dtype
+    y = x.astype(jnp.float32).reshape(-1, d)
+    r = y.shape[0]
+    blocks = 1
+    while blocks < d:
+        y = y.reshape(r, blocks, 2, d // (2 * blocks))
+        a = y[:, :, 0, :]
+        b = y[:, :, 1, :]
+        y = jnp.concatenate([a + b, a - b], axis=-1)
+        blocks *= 2
+    return (y.reshape(orig_shape) / np.sqrt(d)).astype(orig_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "metric", "impl"))
 def block_pull(x, q, arm_idx, blk_idx, *, block: int, metric: str = "l2",
                impl: str = "auto"):
+    """Single-query pull: arm_idx (B,), blk_idx (B, P) → (B, P)."""
     impl = _resolve(impl)
     if impl == "ref":
         return kref.block_pull_ref(x, q, arm_idx, blk_idx, block, metric)
-    return block_pull_pallas(x, q, arm_idx, blk_idx, block=block, metric=metric,
-                             interpret=(impl == "interpret"))
+    return pull_pallas(x, q[None], arm_idx[None], blk_idx[None], block=block,
+                       metric=metric, stats=False,
+                       interpret=(impl == "interpret"))[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "metric", "impl"))
@@ -53,8 +73,8 @@ def block_pull_multi(x, qs, arm_idx, blk_idx, *, block: int, metric: str = "l2",
     impl = _resolve(impl)
     if impl == "ref":
         return kref.block_pull_multi_ref(x, qs, arm_idx, blk_idx, block, metric)
-    return block_pull_multi_pallas(x, qs, arm_idx, blk_idx, block=block,
-                                   metric=metric, interpret=(impl == "interpret"))
+    return pull_pallas(x, qs, arm_idx, blk_idx, block=block, metric=metric,
+                       stats=False, interpret=(impl == "interpret"))
 
 
 @functools.partial(jax.jit,
@@ -69,9 +89,9 @@ def fused_epoch_pull(x, qs, arm_idx, blk_idx, *, block: int,
     impl = _resolve(impl)
     if impl == "ref":
         return kref.fused_epoch_pull_ref(x, qs, arm_idx, blk_idx, block, metric)
-    return fused_epoch_pull_pallas(x, qs, arm_idx, blk_idx, block=block,
-                                   metric=metric, n_buf=n_buf,
-                                   interpret=(impl == "interpret"))
+    return pull_pallas(x, qs, arm_idx, blk_idx, block=block, metric=metric,
+                       stats=True, n_buf=n_buf,
+                       interpret=(impl == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "impl"))

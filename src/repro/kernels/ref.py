@@ -1,30 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel (the correctness references the
-kernel sweep tests assert against, and the fast XLA path on CPU)."""
+kernel sweep tests assert against, and the fast XLA path off the TPU)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-
-
-def fwht_ref(x: jax.Array) -> jax.Array:
-    """Normalized fast Walsh–Hadamard transform along the last axis.
-    x (..., d), d a power of two. Decimation-in-frequency butterfly."""
-    d = x.shape[-1]
-    assert d & (d - 1) == 0, f"d={d} not a power of two"
-    orig_shape = x.shape
-    orig_dtype = x.dtype
-    y = x.astype(jnp.float32).reshape(-1, d)
-    r = y.shape[0]
-    blocks = 1
-    while blocks < d:
-        y = y.reshape(r, blocks, 2, d // (2 * blocks))
-        a = y[:, :, 0, :]
-        b = y[:, :, 1, :]
-        y = jnp.concatenate([a + b, a - b], axis=-1)
-        blocks *= 2
-    y = (y.reshape(orig_shape) / np.sqrt(d)).astype(orig_dtype)
-    return y
 
 
 def block_pull_ref(x: jax.Array, q: jax.Array, arm_idx: jax.Array,
@@ -70,7 +49,7 @@ def block_pull_multi_ref(x: jax.Array, qs: jax.Array, arm_idx: jax.Array,
 def fused_epoch_pull_ref(x: jax.Array, qs: jax.Array, arm_idx: jax.Array,
                          blk_idx: jax.Array, block: int,
                          metric: str = "l2") -> jax.Array:
-    """Round-fused epoch pull (kernels/fused_race.py): T = R·P block pulls
+    """Round-fused epoch pull (kernels/block_pull.py, ``stats=True``): T = R·P block pulls
     per selected arm, reduced to per-arm Welford batch statistics.
     x (n, d_pad); qs (Q, d_pad); arm_idx (Q, B); blk_idx (Q, B, T).
     Returns (Q, B, 2) fp32: (mean, M2) of each arm's T pulled values."""

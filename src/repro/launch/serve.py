@@ -28,6 +28,7 @@ from repro.models import build_model
 from repro.serve.engine import KNNLMConfig, ServeEngine
 from repro.sharding.spec import init_params
 from repro.utils import get_logger
+from repro.utils.compile_cache import use_compile_cache
 
 log = get_logger("repro.serve")
 
@@ -106,6 +107,7 @@ def main(argv=None):
                     help="write the raw trace-event dump here on exit "
                          "(render/convert with tools/trace_view.py)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     entry = get_arch(args.arch)
     cfg = entry.smoke if args.smoke else entry.config

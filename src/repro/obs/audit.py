@@ -162,7 +162,7 @@ def _dense_theta(store, qs_dev, sel: np.ndarray) -> np.ndarray:
     from repro.index.batched_race import _dense_exact_theta
     th = _dense_exact_theta(store.x, qs_dev,
                             jnp.asarray(sel, jnp.int32),
-                            store.cfg.metric, store.d)
+                            store.cfg.metric)
     return np.asarray(th, np.float64)
 
 

@@ -51,12 +51,20 @@ def _data(n=200, d=256, Q=4, seed=0):
 
 
 @pytest.mark.parametrize("bad", [
-    dict(mode="warp"), dict(impl="cuda"), dict(cache="maybe"),
+    dict(mode="warp"), dict(impl="cuda"), dict(impl="pallas"),
+    dict(impl="xla"), dict(cache="maybe"),
     dict(k=0), dict(delta=0.0), dict(delta=1.5), dict(max_rounds=0),
 ])
 def test_query_spec_rejects_bad_fields(bad):
     with pytest.raises(ValueError):
         QuerySpec(**bad)
+
+
+def test_query_spec_impls_are_the_kernel_dispatchers():
+    from repro.kernels import IMPLS
+    assert IMPLS == ("auto", "kernel", "interpret", "ref")
+    for impl in IMPLS:
+        assert QuerySpec(impl=impl).impl == impl
 
 
 def test_query_spec_bind_and_cacheable():

@@ -31,6 +31,21 @@ def test_clustered_dense_shape_and_variance():
     assert np.isfinite(x).all()
 
 
+def test_clustered_dense_row_chunks_match_one_draw(monkeypatch):
+    """The generator draws in row chunks; the corpus is the one a single
+    (n, d) draw from the same seed gives."""
+    from repro.data import synthetic
+    n, d, seed = 50, 33, 3
+    rng = np.random.default_rng(seed)
+    centers = rng.normal(size=(64, d)).astype(np.float32)
+    assign = rng.integers(0, 64, n)
+    scale = (1.0 + rng.exponential(1.0, size=(n, 1))).astype(np.float32)
+    want = centers[assign] + 0.15 * scale * rng.normal(
+        size=(n, d)).astype(np.float32)
+    monkeypatch.setattr(synthetic, "_ROWS", 7)
+    np.testing.assert_array_equal(clustered_dense(n, d, seed=seed), want)
+
+
 def test_clustered_sparse_sparsity():
     x = clustered_sparse(200, 512, sparsity=0.07, seed=0)
     frac = (x != 0).mean()

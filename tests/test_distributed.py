@@ -15,10 +15,7 @@ def _run(prog: str, devices: int = 8, timeout: int = 560):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    # `import repro` first: installs the jax-version compat shims
-    # (repro._compat) before the snippet touches jax.make_mesh/AxisType
-    out = subprocess.run([sys.executable, "-c",
-                          "import repro\n" + textwrap.dedent(prog)],
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(prog)],
                          capture_output=True, text=True, env=env,
                          cwd=ROOT, timeout=timeout)
     assert out.returncode == 0 and "OK" in out.stdout, \

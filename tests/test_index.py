@@ -4,6 +4,7 @@ warm-start plumbing."""
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -13,7 +14,8 @@ from repro.core.datasets import SparseDataset
 from repro.data.synthetic import clustered_sparse, make_knn_benchmark_data
 from repro.index import (IndexStore, build_index, compact, delete, index_knn,
                          insert, load_index, maybe_compact, save_index)
-from repro.index.batched_race import fused_race_topk
+from repro.index.batched_race import _dense_exact_theta, fused_race_topk
+from repro.kernels import ops as kops
 
 
 def _sets(idx):
@@ -46,6 +48,26 @@ def test_batched_parity_rotated():
     store = build_index(corpus, cfg, jax.random.PRNGKey(0))
     res = index_knn(store, queries, jax.random.PRNGKey(1))
     assert _sets(res.indices) == _sets(per.indices)
+
+
+@pytest.mark.parametrize("rotate,d", [(True, 96), (False, 100)])
+def test_exact_theta_on_the_block_pull_scale(rotate, d):
+    """Where the store pads d (to a power of two rotated, to a block
+    multiple otherwise), an arm's exact θ equals the mean of its block
+    pulls over every block — exact and sampled arms race on one scale."""
+    corpus, queries = make_knn_benchmark_data("dense", 64, d, 3, seed=5)
+    cfg = BMOConfig(k=3, block=16, metric="l2", rotate=rotate)
+    store = build_index(corpus, cfg, jax.random.PRNGKey(0))
+    assert store.d_pad != d
+    qs = store.prepare_queries(queries)
+    nb = store.d_pad // store.block
+    arms = jnp.broadcast_to(jnp.arange(8, dtype=jnp.int32), (3, 8))
+    blk = jnp.broadcast_to(jnp.arange(nb, dtype=jnp.int32), (3, 8, nb))
+    pulls = kops.block_pull_multi(store.x, qs, arms, blk, block=16,
+                                  impl="ref")
+    np.testing.assert_allclose(np.mean(np.asarray(pulls), -1),
+                               _dense_exact_theta(store.x, qs, arms, "l2"),
+                               rtol=1e-5)
 
 
 def test_batched_parity_sparse():

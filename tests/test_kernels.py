@@ -1,5 +1,6 @@
 """Per-kernel validation: Pallas (interpret mode = kernel body on CPU)
-against the pure-jnp ref.py oracles, swept over shapes and dtypes."""
+against the pure-jnp ref.py oracles, swept over shapes and dtypes, and the
+XLA Hadamard rotation against the explicit Hadamard matrix."""
 import itertools
 
 import jax
@@ -14,42 +15,48 @@ from repro.kernels import ops, ref
 # FWHT
 # ---------------------------------------------------------------------------
 
+def _hadamard(d):
+    H = np.array([[1.0]])
+    while H.shape[0] < d:
+        H = np.block([[H, H], [H, -H]])
+    return H / np.sqrt(d)
+
+
 @pytest.mark.parametrize("d", [2, 8, 64, 256, 1024])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fwht_matches_ref(rng, d, dtype):
+    """The butterfly equals the explicit normalized Hadamard product."""
     x = jnp.asarray(rng.normal(size=(5, d)).astype(np.float32)).astype(dtype)
-    got = ops.fwht(x, impl="interpret")
-    want = ref.fwht_ref(x)
+    got = ops.fwht(x)
+    want = np.asarray(x, np.float64) @ _hadamard(d).T
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), atol=tol, rtol=tol)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               atol=tol, rtol=tol)
 
 
 def test_fwht_matches_explicit_hadamard(rng):
     d = 32
-    H = np.array([[1.0]])
-    while H.shape[0] < d:
-        H = np.block([[H, H], [H, -H]])
-    H = H / np.sqrt(d)
     x = rng.normal(size=(7, d)).astype(np.float32)
-    got = np.asarray(ops.fwht(jnp.asarray(x), impl="interpret"))
-    np.testing.assert_allclose(got, x @ H.T, atol=1e-5)
+    got = np.asarray(ops.fwht(jnp.asarray(x)))
+    np.testing.assert_allclose(got, x @ _hadamard(d).T, atol=1e-5)
 
 
 def test_fwht_preserves_l2_distances(rng):
     x = jnp.asarray(rng.normal(size=(6, 128)).astype(np.float32))
-    y = ops.fwht(x, impl="interpret")
+    y = ops.fwht(x)
     dx = np.asarray(ref.pairwise_dist_ref(x, x))
     dy = np.asarray(ref.pairwise_dist_ref(y, y))
     np.testing.assert_allclose(dx, dy, atol=1e-3, rtol=1e-4)
 
 
 def test_fwht_row_padding(rng):
-    """n not divisible by the row block."""
-    x = jnp.asarray(rng.normal(size=(3, 64)).astype(np.float32))
-    got = ops.fwht(x, impl="interpret")
-    want = ref.fwht_ref(x)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    """Leading batch axes and an odd row count transform row by row."""
+    x = jnp.asarray(rng.normal(size=(3, 5, 64)).astype(np.float32))
+    got = ops.fwht(x)
+    want = ops.fwht(x.reshape(15, 64)).reshape(3, 5, 64)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got[1]),
+                               np.asarray(x[1]) @ _hadamard(64).T, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -204,3 +211,14 @@ def test_pairwise_zero_distance(rng):
     X = jnp.asarray(rng.normal(size=(5, 128)).astype(np.float32))
     d = np.asarray(ops.pairwise_dist(X, X, metric="l2", impl="interpret"))
     np.testing.assert_allclose(np.diag(d), 0.0, atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# dispatch
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("impl", ["pallas", "xla", "cuda"])
+def test_unknown_impl_is_rejected(impl):
+    x = jnp.zeros((2, 8), jnp.float32)
+    with pytest.raises(ValueError, match="unknown impl"):
+        ops.pairwise_dist(x, x, impl=impl)

@@ -30,8 +30,7 @@ def _run(prog: str, devices: int = 8, timeout: int = 560):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    out = subprocess.run([sys.executable, "-c",
-                          "import repro\n" + textwrap.dedent(prog)],
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(prog)],
                          capture_output=True, text=True, env=env,
                          cwd=ROOT, timeout=timeout)
     assert out.returncode == 0 and "OK" in out.stdout, \
@@ -362,5 +361,33 @@ def test_manifest_reshard_subprocess(tmp_path):
                     for row in np.asarray(got.indices)]
         assert remapped == [set(r.tolist())
                             for r in np.asarray(want.indices)], remapped
+        print("OK")
+    """, devices=4)
+
+
+def test_shards_live_on_their_own_devices_subprocess():
+    """Each shard is built on the device its mesh places it on, and the
+    mesh-sharded corpus is assembled from those very buffers — no device
+    ever stacks or holds another shard's rows."""
+    _run("""
+        import jax, numpy as np
+        from repro.api import Index
+        from repro.configs.base import BMOConfig
+        from repro.data.synthetic import make_knn_benchmark_data
+        corpus, _ = make_knn_benchmark_data("dense", 256, 512, 1, seed=2)
+        cfg = BMOConfig(k=3, delta=0.01, block=64, batch_arms=16,
+                        metric="l2", rotate=True)
+        store = Index.build(corpus, cfg, jax.random.PRNGKey(0),
+                            shards=4).store
+        devs = jax.devices()
+        for s, shard in enumerate(store.shards):
+            for name in ("x", "alive", "prior_var", "signs"):
+                assert getattr(shard, name).devices() == {devs[s]}, (s, name)
+        x = store.device_arrays()["x"]
+        assert x.shape == (4 * store.stride, store.shards[0].d_pad)
+        for piece in x.addressable_shards:
+            own = store.shards[devs.index(piece.device)].x
+            assert (piece.data.unsafe_buffer_pointer()
+                    == own.unsafe_buffer_pointer())
         print("OK")
     """, devices=4)

@@ -1,0 +1,126 @@
+"""Real-size compiles for a described TPU v5e — no chip needed.
+
+The kernels and steps of the retrieval main path are compiled by the TPU
+compiler at the paper's dense shape (capacity 131072, d_pad 16384, block
+128, Q=32, B=64, T=8). Interpret mode cannot see what the chip's compiler
+refuses (block shapes off the (8, 128) tiling, SMEM overflow, HBM
+temporaries); these tests do, a few seconds each.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library at a time, and the worker that runs
+this file keeps it until it exits.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+CAP, D_PAD, BLOCK, Q, B, T = 131072, 16384, 128, 32, 64, 8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these tests
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args, **kw):
+    return jax.jit(fn).lower(*args, **kw).compile()
+
+
+@pytest.mark.parametrize("arms,pulls", [(B, T), (CAP, 2)],
+                         ids=["epoch", "wide_init"])
+def test_fused_epoch_pull_compiles(one_chip, arms, pulls):
+    """The epoch launch, and the wide init's (Q, capacity) launch whose
+    index operands exceed SMEM unless chunked."""
+    s = functools.partial(_spec, one_chip)
+    c = _compile(lambda x, qs, a, b: ops.fused_epoch_pull(
+        x, qs, a, b, block=BLOCK, impl="kernel"),
+        s((CAP, D_PAD)), s((Q, D_PAD)), s((Q, arms), jnp.int32),
+        s((Q, arms, pulls), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_block_pull_multi_compiles(one_chip):
+    s = functools.partial(_spec, one_chip)
+    c = _compile(lambda x, qs, a, b: ops.block_pull_multi(
+        x, qs, a, b, block=BLOCK, impl="kernel"),
+        s((CAP, D_PAD)), s((Q, D_PAD)), s((Q, B), jnp.int32),
+        s((Q, B, T), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_block_pull_compiles(one_chip):
+    s = functools.partial(_spec, one_chip)
+    c = _compile(lambda x, q, a, b: ops.block_pull(
+        x, q, a, b, block=BLOCK, impl="kernel"),
+        s((CAP, D_PAD)), s((D_PAD,)), s((B,), jnp.int32),
+        s((B, T), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("metric", ["l2", "l1"])
+def test_pairwise_dist_compiles(one_chip, metric):
+    s = functools.partial(_spec, one_chip)
+    c = _compile(lambda q, x: ops.pairwise_dist(q, x, metric=metric,
+                                                impl="kernel"),
+                 s((Q, D_PAD)), s((CAP, D_PAD)))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("rows", [Q, 4096], ids=["queries", "build_chunk"])
+def test_rotation_compiles(one_chip, rows):
+    c = _compile(ops.fwht, _spec(one_chip, (rows, D_PAD)))
+    assert c.memory_analysis().temp_size_in_bytes <= 3 * rows * D_PAD * 4
+
+
+def test_build_chunk_fits_one_chip(one_chip):
+    """One build step writes a rotated row chunk into the donated store:
+    the store is aliased, and the transient stays a small multiple of the
+    chunk — so the paper shape builds on a 16 GiB chip."""
+    from repro.index.builder import BUILD_ROWS, _write_rows
+    s = functools.partial(_spec, one_chip)
+    c = _write_rows.lower(s((CAP, D_PAD)), s((CAP,)),
+                          s((BUILD_ROWS, 12288)), s((D_PAD,)),
+                          s((), jnp.int32), block=BLOCK,
+                          metric="l2").compile()
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes >= CAP * D_PAD * 4
+    assert m.temp_size_in_bytes < 8 * BUILD_ROWS * D_PAD * 4
+
+
+def test_wide_init_step_fits_one_chip(one_chip):
+    """The race's wide init over every slot of every query: no
+    lane-padded (Q, capacity, T0) temporaries."""
+    from repro.configs.bmo_nn import DENSE
+    from repro.index.batched_race import _fused_init
+    s = functools.partial(_spec, one_chip)
+    c = _fused_init.lower(s((CAP, D_PAD)), s((Q, D_PAD)),
+                          s((CAP,), jnp.bool_), s((CAP,)),
+                          s((2,), jnp.uint32), cfg=DENSE.bmo, block=BLOCK,
+                          impl="kernel", prior_weight=4.0).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2**28
