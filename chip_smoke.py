@@ -18,8 +18,6 @@ the script exits non-zero and prints no result.
 from __future__ import annotations
 
 import argparse
-import dataclasses
-import functools
 import json
 import os
 import re
@@ -65,43 +63,6 @@ def oracle_mismatches(dist: np.ndarray, rows: np.ndarray, k: int) -> int:
               and np.all(dist[i, r] <= kth[i] + ATOL + RTOL * abs(kth[i])))
         bad += int(not ok)
     return bad
-
-
-def race_step_hlo(index, qs, impl: str) -> str:
-    """Compiled HLO of the race's epoch step for this index, as serving
-    launches it (the sharded step under shard_map for a sharded index)."""
-    import jax
-
-    from repro.core import confidence as conf
-    from repro.index import batched_race as br
-    from repro.index import sharded as sh
-    store, cfg = index.store, index.store.cfg
-    T = max(cfg.epoch_rounds, 1) * cfg.pulls_per_round
-    w = store.prior_weight
-    if index.sharded:
-        dev = store.device_arrays()
-        x, S = dev["x"], store.n_shards
-        nb = x.shape[1] // store.block
-        log_term = float(np.log(2.0 / conf.delta_prime(
-            cfg.delta, S * store.stride, nb)))
-        rng = jax.random.PRNGKey(0)
-        st, pool = jax.eval_shape(
-            sh._fused_init_fn(store.mesh, cfg, store.block, impl, w),
-            x, qs, dev["alive"], dev["prior_var"], rng)
-        step = sh._fused_step_fn(store.mesh, cfg, store.block, store.d,
-                                 impl, True, w, log_term, T)
-        return step.lower(x, qs, st, pool).compile().as_text()
-    x = store.x
-    nb = x.shape[1] // store.block
-    log_term = float(np.log(2.0 / conf.delta_prime(cfg.delta, x.shape[0], nb)))
-    st, pool = jax.eval_shape(
-        functools.partial(br._fused_init, cfg=cfg, block=store.block,
-                          impl=impl, prior_weight=w),
-        x, qs, store.alive, store.prior_var, jax.random.PRNGKey(0))
-    return br._fused_epoch_step.lower(
-        x, qs, st, pool, cfg=cfg, block=store.block, d=store.d, impl=impl,
-        eliminate=True, prior_weight=w, log_term=log_term, T=T
-    ).compile().as_text()
 
 
 def kernels_in(hlo: str) -> list:
@@ -217,8 +178,11 @@ def run(workload=DENSE, *, seed: int = 0, n_queries: int = N_QUERIES,
         f"(compile {rep['query_compile_s']:.3f})")
     check("query", res.indices)
 
-    # 3. the race step as compiled for this device
-    hlo = race_step_hlo(index, store.prepare_queries(queries), impl)
+    # 3. the race's epoch step as compiled for this device: a session
+    #    opened as the plane opens them, lowered by the launch it steps
+    session = index.race(queries[:per], jax.random.PRNGKey(seed + 2),
+                         impl=impl)
+    hlo = session.epoch_hlo()
     rep["custom_call"] = "tpu_custom_call" in hlo
     rep["kernels"] = kernels_in(hlo)
     log(f"race step: tpu_custom_call={rep['custom_call']} "

@@ -32,8 +32,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import BMOConfig
 from repro.index.sharded import (flat_axis_index, guard_local_topk,
-                                 local_dense_race, merge_local_topk)
-from repro.index.batched_race import _dense_exact_theta
+                                 local_dense_race, merge_local_topk,
+                                 model_exact_theta)
 
 
 class DistKNNResult(NamedTuple):
@@ -56,9 +56,10 @@ def _local_knn(x_loc, qs_loc, rng, *, cfg: BMOConfig, d: int, n_loc: int,
                            block=cfg.block, d=d, impl=impl, eliminate=True,
                            prior_weight=0.0, model_axis="model")
     # exact-evaluate the certified local top-k so the merge compares exact
-    # θ values (partial over the model axis → psum), then gather + reduce
-    part = _dense_exact_theta(x_loc, qs_loc, res.indices, cfg.metric)
-    vals = guard_local_topk(res.indices, jax.lax.psum(part, "model"), alive)
+    # θ values (per coordinate slice → pmean over "model"), then gather +
+    # reduce
+    theta = model_exact_theta(x_loc, qs_loc, res.indices, cfg.metric, "model")
+    vals = guard_local_topk(res.indices, theta, alive)
     topk_g = res.indices.astype(jnp.int32) + shard * n_loc
     merged_idx, merged_vals = merge_local_topk(vals, topk_g, dp_axes, cfg.k)
 

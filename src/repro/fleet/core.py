@@ -177,7 +177,7 @@ class Fleet:
                payload=None, max_queue: Optional[int] = None,
                **build_kw) -> Index:
         """Build + register + eagerly checkpoint a namespace. Build kwargs
-        (``placement=``, ``capacity=``, ``impl=``, …) pass through to
+        (``placement=``, ``capacity=``, …) pass through to
         ``Index.build``. ``max_queue`` bounds THIS namespace's admission
         queue on the shared plane (None = fleet/plane default)."""
         self._check_name(name)

@@ -518,6 +518,18 @@ class RaceSession:
     def _step_impl(self) -> bool:
         raise NotImplementedError
 
+    def _epoch_launch(self, R: int):
+        """``(fn, args, kwargs)``: the jitted program one epoch of R rounds
+        launches from the current state — what ``_step_impl`` calls."""
+        raise NotImplementedError(f"{self.kind} sessions expose no epoch launch")
+
+    def epoch_hlo(self, R: Optional[int] = None) -> str:
+        """Compiled HLO text of the next epoch's launch, built by the same
+        ``_epoch_launch`` that ``step()`` calls (R defaults to the first
+        rung of the round chain)."""
+        fn, args, kwargs = self._epoch_launch(R or getattr(self, "_R0", 1))
+        return fn.lower(*args, **kwargs).compile().as_text()
+
     def _apply_force_done(self, mask) -> None:
         raise NotImplementedError
 
@@ -575,6 +587,13 @@ class FusedSession(RaceSession):
                 "n_surv": int(self._n_surv.max(initial=0)),
                 "R": self._last_R}
 
+    def _epoch_launch(self, R: int):
+        return _fused_epoch_step, (self._x, self._qs, self._st, self._pool), \
+            dict(cfg=self._cfg, block=self._block, d=self._d,
+                 impl=self._impl, eliminate=self._eliminate,
+                 prior_weight=self._prior_weight, log_term=self._log_term,
+                 T=R * self._cfg.pulls_per_round)
+
     def _step_impl(self) -> bool:
         need = int(self._n_surv[~self.done].max(initial=1))
         # halve the buffer at most once per epoch (unlike the blocking
@@ -589,11 +608,8 @@ class FusedSession(RaceSession):
             self._st = compact_frontier(self._st, W_new=W_new)
         R = min(self._R0 * pow2_floor(self._W0 // max(need, 1)), self._R_cap)
         R = self._deadline_R(R)
-        st, n_surv, _ = _fused_epoch_step(
-            self._x, self._qs, self._st, self._pool, cfg=self._cfg,
-            block=self._block, d=self._d, impl=self._impl,
-            eliminate=self._eliminate, prior_weight=self._prior_weight,
-            log_term=self._log_term, T=R * self._cfg.pulls_per_round)
+        fn, args, kwargs = self._epoch_launch(R)
+        st, n_surv, _ = fn(*args, **kwargs)
         self._rounds_spent += R
         self._last_R = R
         self._n_surv = host_fetch(n_surv)
@@ -720,6 +736,13 @@ class ShardedFusedSession(RaceSession):
     def _epoch_launches(self, d_rounds: int) -> int:
         return self._S      # one shard-local program per mesh device
 
+    def _epoch_launch(self, R: int):
+        fn = _fused_step_fn(
+            self._mesh, self._cfg, self._store.block, self._store.d,
+            self._impl, self._eliminate, self._prior_weight, self._log_term,
+            R * self._cfg.pulls_per_round)
+        return fn, (self._x_st, self._qs, self._st, self._pool), {}
+
     def _step_impl(self) -> bool:
         active_q = ~self.done
         need = int(self._n_surv[:, active_q].max(initial=1))
@@ -734,11 +757,8 @@ class ShardedFusedSession(RaceSession):
         R = min(self._R0 * pow2_floor((self._S * self._W0)
                                       // max(total_need, 1)), self._R_cap)
         R = self._deadline_R(R)
-        st, n_surv, _ = _fused_step_fn(
-            self._mesh, self._cfg, self._store.block, self._store.d,
-            self._impl, self._eliminate, self._prior_weight, self._log_term,
-            R * self._cfg.pulls_per_round)(self._x_st, self._qs, self._st,
-                                           self._pool)
+        fn, args, kwargs = self._epoch_launch(R)
+        st, n_surv, _ = fn(*args, **kwargs)
         self._rounds_spent += R
         self._last_R = R
         self._n_surv = host_fetch(n_surv)

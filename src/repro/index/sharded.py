@@ -520,6 +520,18 @@ def guard_local_topk(indices, values, alive):
 # exact whenever every shard's local top-k set is (w.h.p. 1 − δ).
 
 
+def model_exact_theta(x_loc, qs, sel, metric: str,
+                      model_axis: Optional[str] = None):
+    """Exact θ for selected slots when the coordinates are split over
+    ``model_axis`` into equal slices: each device's θ is its slice's
+    distance / slice width, and their pmean is the full distance / full
+    width — the scale of the pmean'd block pulls. sel (Q, B) → (Q, B)."""
+    th = _dense_exact_theta(x_loc, qs, sel, metric)
+    if model_axis is not None:
+        th = jax.lax.pmean(th, model_axis)
+    return th
+
+
 def local_dense_race(x_loc, qs, alive, prior, rng, *, cfg: BMOConfig,
                      block: int, d: int, impl: str, eliminate: bool,
                      prior_weight: float, model_axis: Optional[str] = None):
@@ -542,10 +554,7 @@ def local_dense_race(x_loc, qs, alive, prior, rng, *, cfg: BMOConfig,
         return vals
 
     def exact(sel):
-        th = _dense_exact_theta(x_loc, qs, sel, cfg.metric)
-        if model_axis is not None:
-            th = jax.lax.psum(th, model_axis)
-        return th
+        return model_exact_theta(x_loc, qs, sel, cfg.metric, model_axis)
 
     return batched_race_topk(
         pull, exact, n=n_loc, Q=Q,

@@ -26,7 +26,13 @@ Layout, as the TPU compiler requires it:
     so each pull fetches the tile that holds the arm's block (``rows``
     consecutive rows × ``width`` lanes) and masks the reduction down to
     the arm's row and the block's lanes. At fp32 and block 128 that is a
-    4 KiB DMA for 512 useful bytes;
+    4 KiB DMA for 512 useful bytes. The corpus must therefore be whole
+    tiles. ``build_index``'s default capacity, a power of two, is whole
+    rows for n > 4; a rotated d_pad, a power of two, is whole lanes for
+    d > 64; a plain d_pad, a block multiple, is whole lanes for blocks of
+    128 and up. Any other store is zero-padded to whole tiles inside
+    every launch — a copy of the corpus, logged when the compiled kernel
+    is traced;
   * the output block is lane-dense, (n_out, chunk) with one lane per pair,
     resident across the grid; each program writes its own lane.
 """
@@ -38,6 +44,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.utils import get_logger
+
+log = get_logger("repro.kernels")
 
 _LANES = 128
 _SMEM_WORDS = 32768     # block indices per launch: 128 KiB of the 1 MiB SMEM
@@ -159,7 +169,10 @@ def pull_pallas(x: jax.Array, qs: jax.Array, arm_idx: jax.Array,
     rows = 8 * 4 // x.dtype.itemsize
     width = max(block, _LANES)
     pad_r, pad_c = (-n) % rows, (-d_pad) % width
-    if pad_r or pad_c:              # ragged shapes only: stores are aligned
+    if pad_r or pad_c:              # a store that is not whole tiles
+        if not interpret:
+            log.warning("pull kernel pads a (%d, %d) store to whole (%d, %d) "
+                        "tiles in every launch", n, d_pad, rows, width)
         x = jnp.pad(x, ((0, pad_r), (0, pad_c)))
     if pad_c:
         qs = jnp.pad(qs, ((0, 0), (0, pad_c)))

@@ -40,6 +40,51 @@ def test_distributed_knn_exact():
         acc = np.mean([set(np.asarray(res.indices[i])) ==
                        set(np.asarray(ex.indices[i])) for i in range(4)])
         assert acc == 1.0, acc
+        # the merged values are exact θ = distance / d, whatever the
+        # number of coordinate (model) slices
+        np.testing.assert_allclose(np.sort(np.asarray(res.values), 1),
+                                   np.sort(np.asarray(ex.values), 1),
+                                   rtol=1e-4, atol=1e-5)
+        print("OK")
+    """)
+
+
+def test_model_sharded_exact_theta_on_the_block_pull_scale():
+    """With the coordinates split over a model axis, an arm's exact θ (as
+    the race evaluates it) equals the pmean'd block pulls averaged over
+    every block, and the unsharded exact θ."""
+    _run("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import PartitionSpec as P
+        from repro.index.batched_race import _dense_exact_theta
+        from repro.index.sharded import model_exact_theta
+        from repro.kernels import ops
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,)*2)
+        rng = np.random.default_rng(0)
+        n, d, block, Q, B = 32, 384, 64, 3, 8
+        x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+        qs = jnp.asarray(rng.normal(size=(Q, d)).astype(np.float32))
+        sel = jnp.asarray(rng.integers(0, n, (Q, B)), jnp.int32)
+
+        def body(x_loc, q_loc, sel):
+            nb = x_loc.shape[1] // block
+            blk = jnp.broadcast_to(jnp.arange(nb, dtype=jnp.int32),
+                                   sel.shape + (nb,))
+            pulls = ops.block_pull_multi(x_loc, q_loc, sel, blk,
+                                         block=block, impl="ref")
+            pulled = jax.lax.pmean(jnp.mean(pulls, -1), "model")
+            return pulled, model_exact_theta(x_loc, q_loc, sel, "l2", "model")
+
+        sm = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(None, "model"), P(None, "model"), P()),
+                           out_specs=(P(), P()), check_vma=False)
+        pulled, exact = sm(x, qs, sel)
+        whole = _dense_exact_theta(x, qs, sel, "l2")
+        np.testing.assert_allclose(np.asarray(exact), np.asarray(pulled),
+                                   rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(exact), np.asarray(whole),
+                                   rtol=1e-5)
         print("OK")
     """)
 

@@ -70,6 +70,25 @@ def test_exact_theta_on_the_block_pull_scale(rotate, d):
                                rtol=1e-5)
 
 
+def test_session_epoch_hlo_is_the_stepped_launch():
+    """``epoch_hlo`` compiles the program ``step()`` launches, from the same
+    builder, and leaves the session's race untouched."""
+    from repro.api import Index
+    corpus, queries = make_knn_benchmark_data("dense", 256, 512, 4, seed=3)
+    cfg = BMOConfig(k=3, delta=0.01, block=64, batch_arms=16, metric="l2",
+                    rotate=True)
+    index = Index.build(corpus, cfg, jax.random.PRNGKey(0))
+    lowered = index.race(queries, jax.random.PRNGKey(1), impl="ref")
+    plain = index.race(queries, jax.random.PRNGKey(1), impl="ref")
+    hlo = lowered.epoch_hlo()
+    assert hlo.startswith("HloModule jit__fused_epoch_step")
+    for s in (lowered, plain):
+        while s.step():
+            pass
+    for a, b in zip(lowered.snapshot, plain.snapshot):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_batched_parity_sparse():
     corpus = clustered_sparse(200, 2048, seed=4)
     ds = SparseDataset.build(corpus)

@@ -153,6 +153,42 @@ def test_fused_epoch_pull_matches_ref(rng, Q, n, d, block, B, T, metric):
                                rtol=2e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("Q,n,d_pad,block,B,T,max_chunk,smem_words,metric", [
+    # 15 pairs in lane-bound chunks of 4: a padded remainder chunk; 13 rows
+    # and 192 lanes pad the store to whole (8, 128) tiles
+    (3, 13, 192, 64, 5, 3, 4, 1024, "l2"),
+    # SMEM-bound chunks of 8 // 3 = 2 pairs; only the rows are ragged
+    (3, 10, 256, 128, 5, 3, 2048, 8, "l1"),
+    # a store narrower than one lane tile, 7 chunks of 3 pairs
+    (2, 6, 96, 32, 10, 2, 3, 1024, "l2"),
+])
+@pytest.mark.parametrize("stats", [False, True], ids=["pulls", "stats"])
+def test_pull_kernel_chunks_and_ragged_stores(rng, monkeypatch, Q, n, d_pad,
+                                              block, B, T, max_chunk,
+                                              smem_words, metric, stats):
+    """The launch split into several index chunks (ragged remainder
+    included) and a store that is not whole tiles agree with the refs."""
+    import functools
+
+    from repro.kernels import block_pull
+    monkeypatch.setattr(block_pull, "_MAX_CHUNK", max_chunk)
+    monkeypatch.setattr(block_pull, "_SMEM_WORDS", smem_words)
+    X = jnp.asarray(rng.normal(size=(n, d_pad)).astype(np.float32))
+    qs = jnp.asarray(rng.normal(size=(Q, d_pad)).astype(np.float32))
+    arm = jnp.asarray(rng.integers(0, n, (Q, B)), jnp.int32)
+    arm = arm.at[0, 0].set(n - 1)               # the last, padded row tile
+    blk = jnp.asarray(rng.integers(0, d_pad // block, (Q, B, T)), jnp.int32)
+    blk = blk.at[0, 0, 0].set(d_pad // block - 1)
+    got = jax.jit(functools.partial(
+        block_pull.pull_pallas, block=block, metric=metric, stats=stats,
+        interpret=True))(X, qs, arm, blk)
+    want_fn = ref.fused_epoch_pull_ref if stats else ref.block_pull_multi_ref
+    want = want_fn(X, qs, arm, blk, block, metric)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=1e-5)
+
+
 def test_fused_epoch_pull_stats_match_raw_pulls(rng):
     """The kernel's on-chip (mean, M2) reduction over T pulls must merge
     into running state exactly like feeding the T raw per-round pull values

@@ -391,3 +391,29 @@ def test_shards_live_on_their_own_devices_subprocess():
                     == own.unsafe_buffer_pointer())
         print("OK")
     """, devices=4)
+
+
+def test_sharded_session_epoch_hlo_subprocess():
+    """A sharded session lowers its shard_map'd epoch step through the
+    launch builder ``step()`` uses, and then races on unchanged."""
+    _run("""
+        import jax, numpy as np
+        from repro.api import Index
+        from repro.configs.base import BMOConfig
+        from repro.data.synthetic import make_knn_benchmark_data
+        corpus, queries = make_knn_benchmark_data("dense", 256, 512, 4, seed=2)
+        cfg = BMOConfig(k=3, delta=0.01, block=64, batch_arms=16,
+                        metric="l2", rotate=True)
+        index = Index.build(corpus, cfg, jax.random.PRNGKey(0), shards=2)
+        lowered = index.race(queries, jax.random.PRNGKey(1), impl="ref")
+        plain = index.race(queries, jax.random.PRNGKey(1), impl="ref")
+        hlo = lowered.epoch_hlo()
+        assert hlo.startswith("HloModule"), hlo[:80]
+        assert "num_partitions=2" in hlo.splitlines()[0], hlo[:200]
+        for s in (lowered, plain):
+            while s.step():
+                pass
+        for a, b in zip(lowered.snapshot, plain.snapshot):
+            np.testing.assert_array_equal(a, b)
+        print("OK")
+    """, devices=2)
