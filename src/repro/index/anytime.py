@@ -37,6 +37,7 @@ under ``shard_map``, merged on host per snapshot).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -50,7 +51,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import BMOConfig
 from repro.obs import get_obs, new_trace_id
-from repro.obs import profile as obs_profile
 from repro.core import confidence as conf
 from repro.core.ucb import INF
 from repro.utils.hostsync import host_fetch
@@ -146,17 +146,18 @@ def _exactify_frontier(x, qs, st: FrontierState, *, k: int, metric: str,
     qi = jnp.arange(Q)[:, None]
     acc = st.accepted & st.valid
     sel_score = jnp.where(acc & ~st.exact, st.mean, INF)
-    _, pos = jax.lax.top_k(-sel_score, k)
-    need = jnp.take_along_axis(acc & ~st.exact, pos, axis=1)
-    slots = jnp.where(need, jnp.take_along_axis(st.ids, pos, axis=1), 0)
-    vals = jax.lax.cond(
-        jnp.any(need),
-        lambda s: _dense_exact_theta(x, qs, s, metric),
-        lambda s: jnp.zeros(s.shape, jnp.float32), slots)
-    cur = jnp.take_along_axis(st.mean, pos, axis=1)
-    mean = st.mean.at[qi, pos].set(jnp.where(need, vals, cur))
-    exact = st.exact.at[qi, pos].set(
-        jnp.take_along_axis(st.exact, pos, axis=1) | need)
+    with jax.named_scope("repro.exactify"):
+        _, pos = jax.lax.top_k(-sel_score, k)
+        need = jnp.take_along_axis(acc & ~st.exact, pos, axis=1)
+        slots = jnp.where(need, jnp.take_along_axis(st.ids, pos, axis=1), 0)
+        vals = jax.lax.cond(
+            jnp.any(need),
+            lambda s: _dense_exact_theta(x, qs, s, metric),
+            lambda s: jnp.zeros(s.shape, jnp.float32), slots)
+        cur = jnp.take_along_axis(st.mean, pos, axis=1)
+        mean = st.mean.at[qi, pos].set(jnp.where(need, vals, cur))
+        exact = st.exact.at[qi, pos].set(
+            jnp.take_along_axis(st.exact, pos, axis=1) | need)
     return st._replace(
         mean=mean, exact=exact,
         coord_ops=st.coord_ops + jnp.sum(need, 1) * float(d),
@@ -367,13 +368,14 @@ class RaceSession:
     driver's ``_step_impl()``, then records — entirely host-side, from the
     snapshot arrays the drivers already transferred — the epoch's pull /
     coord-op deltas, frontier width, survivors, the CI radius of the worst
-    uncertified position, and (sharded) the per-shard straggler split, as a
-    ``race.epoch`` span under the session's ``sid`` trace id plus registry
-    metrics (DESIGN.md §8.3). Jitted code is untouched.
+    uncertified position, the time blocked on the device (``wait_ms``, every
+    fetch goes through ``_fetch``) and the rest (``host_ms``), and (sharded)
+    the per-shard straggler split, as a ``race.epoch`` span under the
+    session's ``sid`` trace id plus registry metrics (DESIGN.md §8.3). The
+    session's start is a ``race.init`` span. Jitted code is untouched.
     """
 
     kind = "base"
-    kernel = "fused_epoch_pull"   # device kernel this box's epochs launch
 
     def __init__(self, Q: int, k: int, *, obs=None, sid: Optional[str] = None):
         self.Q = Q
@@ -382,6 +384,8 @@ class RaceSession:
         self.obs = obs if obs is not None else get_obs()
         self.sid = sid if sid is not None else new_trace_id("s")
         self.last_epoch: Optional[dict] = None
+        self.race_s = 0.0     # wall time inside race.init and race.epoch
+        self._wait_s = 0.0    # this epoch's time blocked in _fetch
         self.shard_coord_ops: Optional[np.ndarray] = None
         self.shard_rounds: Optional[np.ndarray] = None
         self._snap: Optional[Partial] = None
@@ -425,7 +429,7 @@ class RaceSession:
 
     @property
     def done(self) -> np.ndarray:
-        # host-sync: _snap crossed at the _to_host boundary (numpy)
+        # host-sync: _snap crossed at the _fetch boundary (numpy)
         return np.asarray(self._snap.done) | self._retired
 
     @property
@@ -437,6 +441,30 @@ class RaceSession:
         mask = np.asarray(mask, bool)  # host-sync: caller-side numpy mask
         self._retired |= mask
         self._apply_force_done(jnp.asarray(self._retired))
+
+    def _fetch(self, value, fetch=host_fetch):
+        """``fetch(value)`` (a ``host_fetch``) inside a ``race.sync`` span.
+        Every blocking fetch of a session goes through here; its time is
+        the epoch's ``wait_ms``."""
+        t0 = time.perf_counter()
+        with self.obs.tracer.span("race.sync", trace=self.sid):
+            value = fetch(value)
+        self._wait_s += time.perf_counter() - t0
+        return value
+
+    @contextlib.contextmanager
+    def _race_init(self, width: int):
+        """The ``race.init`` span around a session's start: the wide init's
+        dispatch and the first snapshot fetch. ``coord_ops`` is what the
+        init paid, read from that snapshot."""
+        t0 = time.perf_counter()
+        with self.obs.tracer.annotate("race.init"):
+            yield
+        dur = time.perf_counter() - t0
+        self.race_s += dur
+        self.obs.tracer.complete(
+            "race.init", t0, dur, trace=self.sid, dur_ms=dur * 1e3, Q=self.Q,
+            width=width, coord_ops=float(np.sum(self._snap.coord_ops)))
 
     def step(self) -> bool:
         if self.done.all() or self._rounds_spent >= self._max_rounds:
@@ -451,10 +479,13 @@ class RaceSession:
                                                       float)
                 # host-sync: post-boundary numpy
                 self._prev_shard_rounds = np.array(self.shard_rounds, float)
+        self._wait_s = 0.0
         t0 = time.perf_counter()
-        with obs_profile.annotate(f"repro.race.epoch.{self.kind}"):
+        with self.obs.tracer.annotate(f"race.epoch.{self.kind}"):
             alive = self._step_impl()
-        self._record_epoch(t0, time.perf_counter() - t0)
+        dur = time.perf_counter() - t0
+        self.race_s += dur
+        self._record_epoch(t0, dur)
         return alive
 
     def _record_epoch(self, t0: float, dur: float) -> None:
@@ -500,20 +531,13 @@ class RaceSession:
         reg.histogram("repro_race_epoch_ms",
                       "wall time of one race epoch (ms)",
                       kind=self.kind).observe(dur * 1e3)
-        obs_profile.record_kernel_launch(
-            self.obs, self.kernel,
-            launches=self._epoch_launches(d_rounds),
-            coord_ops=d_coord, pulls=float(d_rounds))  # host-sync: python int
         self.obs.tracer.complete("race.epoch", t0, dur, trace=self.sid,
-                                 dur_ms=dur * 1e3, **info)
+                                 dur_ms=dur * 1e3, wait_ms=self._wait_s * 1e3,
+                                 host_ms=(dur - self._wait_s) * 1e3, **info)
 
     def _epoch_extra(self) -> dict:
         """Per-box epoch attributes (frontier width, survivors, R)."""
         return {}
-
-    def _epoch_launches(self, d_rounds: int) -> int:
-        """Device programs this epoch issued (per-launch accounting)."""
-        return 1
 
     def _step_impl(self) -> bool:
         raise NotImplementedError
@@ -563,20 +587,22 @@ class FusedSession(RaceSession):
         self._floor_w = floor_width(cfg, n, B0=B0)
         prior = store.prior_var if prior is None else jnp.asarray(
             prior, jnp.float32)
-        st, self._pool = _fused_init(
-            x, qs, store.alive, prior, rng, cfg=cfg, block=store.block,
-            impl=impl, prior_weight=prior_weight)
-        self._W0 = st.width
         self._rounds_spent = 0
         self._last_R = 0
         self._n_surv = np.full((self.Q,), n)
-        self._refresh(st)
+        with self._race_init(width=n):
+            st, self._pool = _fused_init(
+                x, qs, store.alive, prior, rng, cfg=cfg, block=store.block,
+                impl=impl, prior_weight=prior_weight)
+            self._W0 = st.width
+            self._refresh(st)
 
     def _refresh(self, st) -> None:
-        self._st, summ = _fused_partial(
-            self._x, self._qs, st, self._pool, cfg=self._cfg, d=self._d,
-            log_term=self._log_term, prior_weight=self._prior_weight)
-        self._snap = _to_host(summ)
+        with self.obs.tracer.span("race.summary", trace=self.sid):
+            self._st, summ = _fused_partial(
+                self._x, self._qs, st, self._pool, cfg=self._cfg, d=self._d,
+                log_term=self._log_term, prior_weight=self._prior_weight)
+            self._snap = self._fetch(summ, _to_host)
 
     def _apply_force_done(self, mask) -> None:
         self._st = _force_done(self._st, mask)
@@ -604,15 +630,16 @@ class FusedSession(RaceSession):
         W_new = max(bucket_width(need, floor=self._floor_w,
                                  current=self._st.width),
                     self._st.width // 2)
-        if W_new < self._st.width:
-            self._st = compact_frontier(self._st, W_new=W_new)
         R = min(self._R0 * pow2_floor(self._W0 // max(need, 1)), self._R_cap)
         R = self._deadline_R(R)
-        fn, args, kwargs = self._epoch_launch(R)
-        st, n_surv, _ = fn(*args, **kwargs)
+        with self.obs.tracer.span("race.launch", trace=self.sid):
+            if W_new < self._st.width:
+                self._st = compact_frontier(self._st, W_new=W_new)
+            fn, args, kwargs = self._epoch_launch(R)
+            st, n_surv, _ = fn(*args, **kwargs)
         self._rounds_spent += R
         self._last_R = R
-        self._n_surv = host_fetch(n_surv)
+        self._n_surv = self._fetch(n_surv)
         self.epochs += 1
         self._refresh(st)
         return not self.done.all()
@@ -623,7 +650,6 @@ class SparseRoundsSession(RaceSession):
     chunks (one chunk = one scheduler epoch)."""
 
     kind = "sparse"
-    kernel = "block_pull_multi"
 
     def __init__(self, store, queries, rng, *, cfg: BMOConfig,
                  eliminate: bool = True, prior=None,
@@ -644,10 +670,11 @@ class SparseRoundsSession(RaceSession):
         self._max_rounds = cfg.max_rounds or int(
             2 * math.ceil(n * mp / max(B0 * cfg.pulls_per_round, 1)) + n + 16)
         self._rounds_spent = 0
-        self._st, summ = _sparse_sess_init(
-            *self._args, rng, cfg=cfg, d=store.d, eliminate=eliminate,
-            prior_weight=prior_weight)
-        self._snap = _to_host(summ)
+        with self._race_init(width=n):
+            self._st, summ = _sparse_sess_init(
+                *self._args, rng, cfg=cfg, d=store.d, eliminate=eliminate,
+                prior_weight=prior_weight)
+            self._snap = self._fetch(summ, _to_host)
 
     def _apply_force_done(self, mask) -> None:
         self._st = _force_done(self._st, mask)
@@ -655,17 +682,14 @@ class SparseRoundsSession(RaceSession):
     def _epoch_extra(self) -> dict:
         return {"R": self._chunk}
 
-    def _epoch_launches(self, d_rounds: int) -> int:
-        # the chunked while-loop issues one block_pull_multi per round
-        return max(int(d_rounds), 1)
-
     def _step_impl(self) -> bool:
-        self._st, summ = _sparse_sess_chunk(
-            *self._args, self._st, cfg=self._cfg, d=self._d,
-            eliminate=self._eliminate, prior_weight=self._prior_weight,
-            rounds=self._chunk)
+        with self.obs.tracer.span("race.launch", trace=self.sid):
+            self._st, summ = _sparse_sess_chunk(
+                *self._args, self._st, cfg=self._cfg, d=self._d,
+                eliminate=self._eliminate, prior_weight=self._prior_weight,
+                rounds=self._chunk)
         self._rounds_spent += self._chunk
-        self._snap = _to_host(summ)
+        self._snap = self._fetch(summ, _to_host)
         self.epochs += 1
         return not self.done.all()
 
@@ -704,21 +728,23 @@ class ShardedFusedSession(RaceSession):
         self._max_rounds = cfg.max_rounds or int(
             2 * math.ceil(self._stride * nb / max(B0 * P_, 1))
             + self._stride + 16)
-        st, self._pool = _fused_init_fn(
-            self._mesh, cfg, store.block, impl, prior_weight)(
-            self._x_st, qs, alive_st, prior_st, rng)
-        self._W0 = st.ids.shape[2]
         self._rounds_spent = 0
         self._last_R = 0
         self._n_surv = np.full((self._S, self.Q), self._stride)
-        self._refresh(st)
+        with self._race_init(width=self._stride):
+            st, self._pool = _fused_init_fn(
+                self._mesh, cfg, store.block, impl, prior_weight)(
+                self._x_st, qs, alive_st, prior_st, rng)
+            self._W0 = st.ids.shape[2]
+            self._refresh(st)
 
     def _refresh(self, st) -> None:
-        self._st, summ = _sharded_fused_partial_fn(
-            self._mesh, self._cfg, self._store.d, self._log_term,
-            self._prior_weight, self._stride)(
-            self._x_st, self._qs, st, self._pool)
-        per_shard = Partial(*host_fetch(tuple(summ)))
+        with self.obs.tracer.span("race.summary", trace=self.sid):
+            self._st, summ = _sharded_fused_partial_fn(
+                self._mesh, self._cfg, self._store.d, self._log_term,
+                self._prior_weight, self._stride)(
+                self._x_st, self._qs, st, self._pool)
+            per_shard = self._fetch(summ, _to_host)
         self.shard_coord_ops = per_shard.coord_ops.sum(axis=1)
         self.shard_rounds = per_shard.rounds.max(axis=1)
         self._snap = _merge_shard_partials(per_shard)
@@ -732,9 +758,6 @@ class ShardedFusedSession(RaceSession):
         return {"width": int(self._st.ids.shape[2]),
                 "n_surv": int(self._n_surv.max(initial=0)),
                 "R": self._last_R, "shards": self._S}
-
-    def _epoch_launches(self, d_rounds: int) -> int:
-        return self._S      # one shard-local program per mesh device
 
     def _epoch_launch(self, R: int):
         fn = _fused_step_fn(
@@ -750,18 +773,19 @@ class ShardedFusedSession(RaceSession):
         W_new = max(bucket_width(need, floor=self._floor_w,
                                  current=self._st.ids.shape[2]),
                     self._st.ids.shape[2] // 2)
-        if W_new < self._st.ids.shape[2]:
-            self._st = _compact_stacked(self._st, W_new=W_new)
         total_need = int(
             np.sum(self._n_surv[:, active_q].max(axis=1, initial=0)))
         R = min(self._R0 * pow2_floor((self._S * self._W0)
                                       // max(total_need, 1)), self._R_cap)
         R = self._deadline_R(R)
-        fn, args, kwargs = self._epoch_launch(R)
-        st, n_surv, _ = fn(*args, **kwargs)
+        with self.obs.tracer.span("race.launch", trace=self.sid):
+            if W_new < self._st.ids.shape[2]:
+                self._st = _compact_stacked(self._st, W_new=W_new)
+            fn, args, kwargs = self._epoch_launch(R)
+            st, n_surv, _ = fn(*args, **kwargs)
         self._rounds_spent += R
         self._last_R = R
-        self._n_surv = host_fetch(n_surv)
+        self._n_surv = self._fetch(n_surv)
         self.epochs += 1
         self._refresh(st)
         return not self.done.all()
@@ -772,7 +796,6 @@ class ShardedSparseSession(RaceSession):
     ``shard_map`` (each chunk one collective program), merged per snapshot."""
 
     kind = "sharded_sparse"
-    kernel = "block_pull_multi"
 
     def __init__(self, store: ShardedIndexStore, queries, rng, *,
                  cfg: BMOConfig, eliminate: bool = True, prior_st=None,
@@ -799,14 +822,14 @@ class ShardedSparseSession(RaceSession):
                           / max(B0 * cfg.pulls_per_round, 1))
             + self._stride + 16)
         self._rounds_spent = 0
-        st, summ = _sharded_sparse_init_fn(
-            self._mesh, cfg, store.d, eliminate, prior_weight,
-            self._stride)(*self._args, rng)
-        self._st = st
-        self._ingest(summ)
+        with self._race_init(width=self._stride):
+            self._st, summ = _sharded_sparse_init_fn(
+                self._mesh, cfg, store.d, eliminate, prior_weight,
+                self._stride)(*self._args, rng)
+            self._ingest(summ)
 
     def _ingest(self, summ) -> None:
-        per_shard = Partial(*host_fetch(tuple(summ)))
+        per_shard = self._fetch(summ, _to_host)
         self.shard_coord_ops = per_shard.coord_ops.sum(axis=1)
         self.shard_rounds = per_shard.rounds.max(axis=1)
         self._snap = _merge_shard_partials(per_shard)
@@ -817,14 +840,12 @@ class ShardedSparseSession(RaceSession):
     def _epoch_extra(self) -> dict:
         return {"R": self._chunk, "shards": self._S}
 
-    def _epoch_launches(self, d_rounds: int) -> int:
-        return max(int(d_rounds), 1) * self._S
-
     def _step_impl(self) -> bool:
-        self._st, summ = _sharded_sparse_chunk_fn(
-            self._mesh, self._cfg, self._d, self._eliminate,
-            self._prior_weight, self._stride, self._chunk)(
-            *self._args, self._st)
+        with self.obs.tracer.span("race.launch", trace=self.sid):
+            self._st, summ = _sharded_sparse_chunk_fn(
+                self._mesh, self._cfg, self._d, self._eliminate,
+                self._prior_weight, self._stride, self._chunk)(
+                *self._args, self._st)
         self._rounds_spent += self._chunk
         self.epochs += 1
         self._ingest(summ)

@@ -47,7 +47,6 @@ from repro.core.datasets import SparseDataset
 from repro.core.ucb import (INF, acceptance_step, acceptance_step_masked,
                             topk_from_state, topk_from_state_masked)
 from repro.obs import get_obs
-from repro.obs import profile as obs_profile
 from repro.utils.hostsync import host_fetch
 from repro.index.frontier import (FrontierState, bucket_width,
                                   compact_frontier, floor_width, pow2_floor,
@@ -360,7 +359,7 @@ def _fused_init(x, qs, alive, prior_var, rng, *, cfg: BMOConfig, block: int,
     rng, sub = jax.random.split(rng)
     all_arms = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None], (Q, n))
     blk = draw_blocks(sub, (Q, n, T0), nb)
-    with jax.named_scope("repro.fused_epoch_pull"):
+    with jax.named_scope("repro.fused_init_pull"):
         stats = kops.fused_epoch_pull(x, qs, all_arms, blk, block=block,
                                       metric=cfg.metric, impl=impl,
                                       n_buf=cfg.kernel_buffers)
@@ -531,6 +530,12 @@ def fused_race_topk(x, qs, alive, prior_var, rng, *, cfg: BMOConfig,
     done = np.zeros((Q,), bool)
     obs = get_obs()
     prev_coord = float(np.sum(host_fetch(st.coord_ops)))
+    epoch_ms = obs.registry.histogram(
+        "repro_race_epoch_ms", "wall time of one race epoch (ms)",
+        kind="fused_blocking")
+    coord_total = obs.registry.counter(
+        "repro_race_coord_ops_total", "coordinate reads paid by race epochs",
+        kind="fused_blocking")
     while not done.all() and rounds_spent < max_rounds:
         # adaptive reallocation (Neufeld et al. style): as the candidate
         # frontier shrinks by c×, fuse c× more rounds into the next launch —
@@ -544,25 +549,22 @@ def fused_race_topk(x, qs, alive, prior_var, rng, *, cfg: BMOConfig,
                 st = compact_frontier(st, W_new=W_new)
         R = min(R0 * pow2_floor(W0 // max(need, 1)), R_cap)
         t0 = time.perf_counter()
-        with obs_profile.annotate("repro.race.epoch.fused_blocking"):
-            st, n_surv_d, done_d = _fused_epoch_step(
-                x, qs, st, prior_pool, cfg=cfg, block=block, d=d, impl=impl,
-                eliminate=eliminate, prior_weight=prior_weight,
-                log_term=log_term, T=R * P)
+        with obs.tracer.annotate("race.epoch.fused_blocking"):
+            with obs.tracer.span("race.launch"):
+                st, n_surv_d, done_d = _fused_epoch_step(
+                    x, qs, st, prior_pool, cfg=cfg, block=block, d=d,
+                    impl=impl, eliminate=eliminate,
+                    prior_weight=prior_weight, log_term=log_term, T=R * P)
             rounds_spent += R
-            # the per-epoch boundary: survivor count + done flags must
-            # cross to host to drive the Python reallocation loop
-            n_surv, done = host_fetch((n_surv_d, done_d))
-        # n_surv/done already crossed to host, so the per-launch accounting
-        # adds no extra device round-trip beyond the coord-op scalar
-        coord = float(np.sum(host_fetch(st.coord_ops)))
-        obs.registry.histogram(
-            "repro_race_epoch_ms", "wall time of one race epoch (ms)",
-            kind="fused_blocking").observe((time.perf_counter() - t0) * 1e3)
-        obs_profile.record_kernel_launch(
-            obs, "fused_epoch_pull", launches=1,
-            coord_ops=max(coord - prev_coord, 0.0),
-            pulls=float(R))  # host-sync: python int
+            # the per-epoch boundary: survivor count, done flags and the
+            # coordinates paid cross to host in one fetch, to drive the
+            # Python reallocation loop and the epoch's counters
+            with obs.tracer.span("race.sync"):
+                n_surv, done, coord_ops = host_fetch(
+                    (n_surv_d, done_d, st.coord_ops))
+        epoch_ms.observe((time.perf_counter() - t0) * 1e3)
+        coord = float(np.sum(coord_ops))  # host-sync: fetched above
+        coord_total.inc(max(coord - prev_coord, 0.0))
         prev_coord = coord
 
     topk, topk_vals, n_exact = _fused_finalize(

@@ -75,15 +75,16 @@ def compact_frontier(st: FrontierState, *, W_new: int) -> FrontierState:
     queries the caller guarantees W_new ≥ survivor count (nothing live is
     ever dropped). Statistics ride along untouched.
     """
-    key = jnp.where(st.accepted, 0, jnp.where(survivors(st), 1, 2))
-    order = jnp.argsort(key, axis=1)[:, :W_new]
-    take = lambda a: jnp.take_along_axis(a, order, axis=1)
-    return st._replace(
-        ids=take(st.ids), mean=take(st.mean), count=take(st.count),
-        m2=take(st.m2), prior=take(st.prior), exact=take(st.exact),
-        accepted=take(st.accepted), rejected=take(st.rejected),
-        valid=take(st.valid) & ~take(st.rejected),
-    )
+    with jax.named_scope("repro.compact_frontier"):
+        key = jnp.where(st.accepted, 0, jnp.where(survivors(st), 1, 2))
+        order = jnp.argsort(key, axis=1)[:, :W_new]
+        take = lambda a: jnp.take_along_axis(a, order, axis=1)
+        return st._replace(
+            ids=take(st.ids), mean=take(st.mean), count=take(st.count),
+            m2=take(st.m2), prior=take(st.prior), exact=take(st.exact),
+            accepted=take(st.accepted), rejected=take(st.rejected),
+            valid=take(st.valid) & ~take(st.rejected),
+        )
 
 
 def bucket_width(need: int, *, floor: int, current: int) -> int:

@@ -73,8 +73,8 @@ from repro.index import mutable
 from repro.index.store import IndexStore
 from repro.kernels import ops as kops
 from repro.obs import get_obs
-from repro.obs import profile as obs_profile
 from repro.utils import get_logger
+from repro.utils.hostsync import host_fetch
 
 log = get_logger("repro.index")
 
@@ -744,7 +744,9 @@ def _sharded_fused_race(store: ShardedIndexStore, qs, prior_st, rng, *,
     n_surv = np.full((S, Q), stride)
     done = np.zeros((S, Q), bool)
     obs = get_obs()
-    prev_coord = 0.0
+    epoch_ms = obs.registry.histogram(
+        "repro_race_epoch_ms", "wall time of one race epoch (ms)",
+        kind="sharded_fused_blocking")
     while not done.all() and rounds_spent < max_rounds:
         active = ~done
         need = int(n_surv[active].max(initial=1))
@@ -761,23 +763,17 @@ def _sharded_fused_race(store: ShardedIndexStore, qs, prior_st, rng, *,
                          for s in range(S))
         R = min(R0 * pow2_floor((S * W0) // max(total_need, 1)), R_cap)
         t0 = time.perf_counter()
-        st, n_surv_d, done_d = _fused_step_fn(
-            mesh, cfg, block, store.d, impl, eliminate, prior_weight,
-            log_term, R * P_)(x_st, qs, st, pool)
-        rounds_spent += R
-        n_surv = np.asarray(n_surv_d)
-        done = np.asarray(done_d)
+        with obs.tracer.annotate("race.epoch.sharded_fused_blocking"):
+            with obs.tracer.span("race.launch"):
+                st, n_surv_d, done_d = _fused_step_fn(
+                    mesh, cfg, block, store.d, impl, eliminate,
+                    prior_weight, log_term, R * P_)(x_st, qs, st, pool)
+            rounds_spent += R
+            with obs.tracer.span("race.sync"):
+                n_surv, done = host_fetch((n_surv_d, done_d))
         # per-epoch timing under the same histogram the anytime sessions
         # feed — repro.tune races candidate configs on this series
-        coord = float(np.sum(np.asarray(st.coord_ops)))
-        obs.registry.histogram(
-            "repro_race_epoch_ms", "wall time of one race epoch (ms)",
-            kind="sharded_fused_blocking").observe(
-            (time.perf_counter() - t0) * 1e3)
-        obs_profile.record_kernel_launch(
-            obs, "fused_epoch_pull", launches=S,
-            coord_ops=max(coord - prev_coord, 0.0), pulls=float(R))
-        prev_coord = coord
+        epoch_ms.observe((time.perf_counter() - t0) * 1e3)
 
     outs = _fused_finalize_fn(mesh, cfg, log_term, prior_weight, stride,
                               block, store.d, cfg.metric)(x_st, qs, st, pool)

@@ -13,9 +13,9 @@ One ``ObsContext`` bundles the three primitives every layer records into:
 ``get_obs()`` returns the process-default context (what the launchers
 export); tests and embedders can pass their own ``ObsContext`` to
 ``RequestPlane`` / ``make_session`` for isolation. ``REPRO_OBS=0``
-disables event/span recording process-wide (metrics counters stay on —
-``ServeStats`` must keep working); ``REPRO_OBS_EVENTS`` sizes the default
-ring.
+disables event/span recording and the spans' profiler annotations
+process-wide (metrics counters stay on — ``ServeStats`` must keep
+working); ``REPRO_OBS_EVENTS`` sizes the default ring.
 """
 from __future__ import annotations
 

@@ -9,9 +9,9 @@ exporter thread at worst sees one stale slot, never a partial event).
 
 Naming scheme (§8.2): ``repro_<subsystem>_<what>[_<unit>][_total]`` —
 e.g. ``repro_plane_submitted_total``, ``repro_race_epoch_ms``,
-``repro_kernel_coord_ops_total``. Counters end in ``_total``; durations are
+``repro_race_coord_ops_total``. Counters end in ``_total``; durations are
 milliseconds; labels distinguish instances (``plane="p0"``) and kinds
-(``kernel="fused_epoch_pull"``), never unbounded values like trace ids.
+(``kind="fused"``), never unbounded values like trace ids.
 """
 from __future__ import annotations
 

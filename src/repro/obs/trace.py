@@ -12,14 +12,27 @@ event carries ``session=<sid>`` as the join key.
 Spans are recorded *at end* (one event each, into the bounded ring), so an
 abandoned span costs nothing. All timing is ``time.perf_counter()`` on one
 clock; exporters convert to microseconds.
+
+A span opened and closed in one frame (``Tracer.span``, or ``annotate``
+around a phase later recorded by ``complete``) is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so a profiler
+capture names each idle gap of the device by the host phase that caused
+it. Spans that cross calls (``Tracer.start``, e.g. ``plane.queue``) stay in
+the event log only: an annotation left open across the caller's waits
+would claim them.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs.registry import EventLog
+
+_NO_ANNOTATION = contextlib.nullcontext()
 
 _ids = itertools.count()
 
@@ -30,16 +43,22 @@ def new_trace_id(prefix: str) -> str:
 
 
 class Span:
-    """An open span; ``end()`` records it. Usable as a context manager."""
+    """An open span; ``end()`` records it. Usable as a context manager.
+    With ``annotate`` it is also open as a profiler annotation until
+    ``end()``."""
 
-    __slots__ = ("_tracer", "name", "trace", "t0", "attrs", "_open")
+    __slots__ = ("_tracer", "name", "trace", "t0", "attrs", "_open", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, trace: Optional[str],
-                 attrs: dict):
+                 attrs: dict, annotate: bool = False):
         self._tracer = tracer
         self.name = name
         self.trace = trace
         self.attrs = attrs
+        self._ann = None
+        if annotate:
+            self._ann = TraceAnnotation(f"repro.{name}")
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         self._open = True
 
@@ -47,10 +66,12 @@ class Span:
         if not self._open:          # idempotent: double-end records once
             return
         self._open = False
+        dur = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if attrs:
             self.attrs.update(attrs)
-        self._tracer.complete(self.name, self.t0,
-                              time.perf_counter() - self.t0,
+        self._tracer.complete(self.name, self.t0, dur,
                               trace=self.trace, **self.attrs)
 
     def __enter__(self) -> "Span":
@@ -97,8 +118,18 @@ class Tracer:
         return Span(self, name, trace, attrs)
 
     def span(self, name: str, trace: Optional[str] = None, **attrs):
-        """Context-manager form for lexically scoped phases."""
-        return self.start(name, trace, **attrs)
+        """Context-manager form for lexically scoped phases; also the
+        profiler annotation ``repro.<name>`` while open."""
+        if not self.enabled:
+            return NULL_SPAN
+        return Span(self, name, trace, attrs, annotate=True)
+
+    def annotate(self, name: str):
+        """The profiler annotation ``repro.<name>`` alone, for a phase the
+        caller times and records itself with ``complete``."""
+        if not self.enabled:
+            return _NO_ANNOTATION
+        return TraceAnnotation(f"repro.{name}")
 
     def complete(self, name: str, t0: float, dur: float,
                  trace: Optional[str] = None, **attrs) -> None:

@@ -195,6 +195,7 @@ class RequestPlane:
         self._entries: Dict[int, _Entry] = {}
         self._latencies: collections.deque = collections.deque(
             maxlen=self.config.latency_window)
+        self._race_s = 0.0    # wall time inside the sessions' race spans
         # the metrics registry is the single source of truth for the plane
         # counters (DESIGN.md §8.2): ``stats`` and the exporters read the
         # SAME series, so they can never disagree
@@ -621,6 +622,7 @@ class RequestPlane:
                 self.obs.tracer.instant("plane.shed", trace=t.trace_id,
                                         reason=t.reason)
             return
+        self._race_s += session.race_s
         if pad:
             # pow2 pad rows belong to no ticket: retire them immediately so
             # they neither race nor dilute the adaptive pull reallocation
@@ -746,47 +748,70 @@ class RequestPlane:
 
     def step(self) -> int:
         """One scheduler epoch: fence, admit, advance every active group by
-        one epoch, harvest terminals. Returns tickets still in flight."""
+        one epoch, harvest terminals. Returns tickets still in flight.
+
+        The pass is a ``plane.step`` span (recorded, like
+        ``repro_plane_epoch_ms``, only on passes with groups or queued
+        tickets) over ``plane.admission`` (fence, admission and group
+        launches) and ``plane.harvest``; its ``self_ms`` is its wall time
+        less the ``race.init`` and ``race.epoch`` spans inside it."""
+        tracer = self.obs.tracer
         t0 = time.perf_counter()
-        now = time.monotonic()
-        self._fence_groups()
-        self._admit_groups(now)
-        # a TRUE idle pass: the epoch began with nothing racing and nothing
-        # queued — only such passes may do shadow-audit work below, so the
-        # step that *finishes* the last ticket (drain's final iteration)
-        # never pays the oracle either
-        idle_pass = not self._groups and not self._queues
-        if self._groups:
-            self._epochs.inc()
-        for group in list(self._groups):
-            self._harvest(group, count_epoch=False)   # pre-step expiries
-            if group not in self._groups:
-                continue
-            group.session.step()
-            self._harvest(group, count_epoch=True)
-        # expire queued tickets whose deadline passed while waiting
-        now = time.monotonic()
-        for q in self._queues.values():
-            for entry in [e for e in q if self._deadline_passed(e, now)]:
-                q.remove(entry)
-                entry.epoch = entry.index.epoch
-                self._finish(entry, R_DEADLINE)
-        # drop drained queues: distinct (tenant, namespace) pairs must not
-        # grow the admission scan (or stats) without bound on a long plane
-        for key in [key for key, q in self._queues.items() if not q]:
-            del self._queues[key]
-        if self._groups or self.active:
-            self._h_epoch.observe((time.perf_counter() - t0) * 1e3)
-        self._g_queue.set(sum(len(q) for q in self._queues.values()))
-        self._g_active.set(sum(len(g.members) for g in self._groups))
-        for ns, depth in self.ns_queue_depth().items():
-            self._ns_metrics(ns)[2].set(depth)
-        # shadow audits use IDLE steps only: with races active or tickets
-        # queued the oracle never runs inside the serving epoch — audit
-        # work is demonstrably off the critical path (DESIGN.md §10.2)
-        if (self.auditor is not None and idle_pass
-                and not self._groups and not self._queues):
-            self.auditor.process(1)
+        race0 = self._race_s
+        with tracer.annotate("plane.step"):
+            now = time.monotonic()
+            with tracer.span("plane.admission", trace=self.plane_id):
+                self._fence_groups()
+                self._admit_groups(now)
+            # a TRUE idle pass: the epoch began with nothing racing and
+            # nothing queued — only such passes may do shadow-audit work
+            # below, so the step that *finishes* the last ticket (drain's
+            # final iteration) never pays the oracle either
+            idle_pass = not self._groups and not self._queues
+            if self._groups:
+                self._epochs.inc()
+            for group in list(self._groups):
+                with tracer.span("plane.harvest", trace=self.plane_id):
+                    self._harvest(group, count_epoch=False)  # pre-step expiries
+                if group not in self._groups:
+                    continue
+                session = group.session
+                before = session.race_s
+                session.step()
+                self._race_s += session.race_s - before
+                with tracer.span("plane.harvest", trace=self.plane_id):
+                    self._harvest(group, count_epoch=True)
+            # expire queued tickets whose deadline passed while waiting
+            now = time.monotonic()
+            for q in self._queues.values():
+                for entry in [e for e in q if self._deadline_passed(e, now)]:
+                    q.remove(entry)
+                    entry.epoch = entry.index.epoch
+                    self._finish(entry, R_DEADLINE)
+            # drop drained queues: distinct (tenant, namespace) pairs must
+            # not grow the admission scan (or stats) without bound on a
+            # long plane
+            for key in [key for key, q in self._queues.items() if not q]:
+                del self._queues[key]
+            busy = bool(self._groups or self.active)
+            if busy:
+                self._h_epoch.observe((time.perf_counter() - t0) * 1e3)
+            self._g_queue.set(sum(len(q) for q in self._queues.values()))
+            self._g_active.set(sum(len(g.members) for g in self._groups))
+            for ns, depth in self.ns_queue_depth().items():
+                self._ns_metrics(ns)[2].set(depth)
+            # shadow audits use IDLE steps only: with races active or
+            # tickets queued the oracle never runs inside the serving epoch
+            # — audit work is demonstrably off the critical path
+            # (DESIGN.md §10.2)
+            if (self.auditor is not None and idle_pass
+                    and not self._groups and not self._queues):
+                self.auditor.process(1)
+        if busy:
+            dur = time.perf_counter() - t0
+            tracer.complete("plane.step", t0, dur, trace=self.plane_id,
+                            dur_ms=dur * 1e3,
+                            self_ms=(dur - (self._race_s - race0)) * 1e3)
         return self.active
 
     def drain(self, max_epochs: int = 100000) -> None:

@@ -5,13 +5,14 @@ S=1, plus S=4 on a forced 4-device mesh as a subprocess), the shed span,
 the no-epoch-mixing guarantee across the mutation fence (both modes), the
 empty-window latency-percentile regression, structured trace-id logging,
 the Chrome-trace writer, the committed sample trace render, and the
-kernel launch/coord-op accounting counters.
+race and plane phase spans (event log and profiler annotations).
 
 Every plane/race test uses a private ``ObsContext`` injected via the
 ``obs=`` kwarg so tests never race each other through the process-default
 context.
 """
 import collections
+import contextlib
 import json
 import logging
 import math
@@ -543,38 +544,177 @@ def test_committed_sample_trace_renders():
 
 
 # ---------------------------------------------------------------------------
-# kernel accounting
+# race and plane phase spans
 # ---------------------------------------------------------------------------
 
 
-def test_kernel_launch_and_coord_op_counters():
-    obs = ObsContext("t")
-    idx, queries = _dense_index()
+def _raced(obs, kind="dense"):
+    idx, queries = _sparse_index() if kind == "sparse" else _dense_index()
     s = idx.race(queries, jax.random.PRNGKey(0), obs=obs)
     while s.step():
         pass
-    series = {(m.name, dict(m.labels).get("kernel")): m.value
-              for m in obs.registry.collect()
-              if m.name.startswith("repro_kernel_")}
-    launches = series.get(("repro_kernel_launches_total",
-                           "fused_epoch_pull"), 0)
-    coord = series.get(("repro_kernel_coord_ops_total",
-                        "fused_epoch_pull"), 0)
-    assert launches >= 1
-    assert coord > 0
-    # per-launch accounting matches the session's own cumulative counter
-    total = float(np.sum(s.snapshot.coord_ops))
-    assert coord <= total                 # init pulls excluded from epochs
+    return s
 
-    obs2 = ObsContext("t2")
-    sidx, sq = _sparse_index()
-    s2 = sidx.race(sq, jax.random.PRNGKey(0), obs=obs2)
-    while s2.step():
-        pass
-    series2 = {dict(m.labels).get("kernel") for m in
-               obs2.registry.collect()
-               if m.name == "repro_kernel_launches_total"}
-    assert "block_pull_multi" in series2
+
+def test_race_epoch_splits_into_wait_and_host():
+    obs = ObsContext("t")
+    s = _raced(obs)
+    race = _events(obs, "race.epoch", s.sid)
+    assert race
+    for e in race:
+        a = e["attrs"]
+        assert a["wait_ms"] > 0.0 and a["host_ms"] > 0.0
+        assert a["wait_ms"] + a["host_ms"] == pytest.approx(a["dur_ms"])
+        assert a["dur_ms"] == pytest.approx(e["dur"] * 1e3)
+    # two fetches an epoch (survivors, snapshot), each a race.sync span
+    sync = _events(obs, "race.sync", s.sid)
+    assert len(sync) == 2 * len(race) + 1          # + the init's snapshot
+    assert len(_events(obs, "race.launch", s.sid)) == len(race)
+    assert len(_events(obs, "race.summary", s.sid)) == len(race) + 1
+
+
+@pytest.mark.parametrize("kind", ["dense", "sparse"])
+def test_init_and_epoch_coord_ops_add_up_to_the_total(kind):
+    """The race.init span carries the coordinates the wide init paid; with
+    every epoch's delta they account for the session's whole total."""
+    obs = ObsContext("t")
+    s = _raced(obs, kind)
+    init = _events(obs, "race.init", s.sid)
+    assert len(init) == 1
+    a = init[0]["attrs"]
+    assert a["Q"] == s.Q and a["width"] > 0 and a["coord_ops"] > 0.0
+    assert a["dur_ms"] == pytest.approx(init[0]["dur"] * 1e3)
+    epochs = sum(e["attrs"]["coord_ops"]
+                 for e in _events(obs, "race.epoch", s.sid))
+    total = float(np.sum(s.snapshot.coord_ops))
+    assert a["coord_ops"] + epochs == pytest.approx(total)
+    series = {m.name: m.value for m in obs.registry.collect()
+              if m.name == "repro_race_coord_ops_total"}
+    assert series["repro_race_coord_ops_total"] == pytest.approx(epochs)
+
+
+def test_blocking_driver_counts_epoch_coord_ops():
+    """The blocking fused driver fetches its coordinate total with the
+    survivor count, once an epoch, and counts the epochs' share."""
+    from repro.index.batched_race import index_knn
+    from repro.obs import set_obs
+    idx, queries = _dense_index()
+    cfg = idx.store.cfg
+    obs = ObsContext("t")
+    old = set_obs(obs)
+    try:
+        res = index_knn(idx.store, queries, jax.random.PRNGKey(0),
+                        mode="fused")
+    finally:
+        set_obs(old)
+    counted = sum(m.value for m in obs.registry.collect()
+                  if m.name == "repro_race_coord_ops_total"
+                  and dict(m.labels).get("kind") == "fused_blocking")
+    T0 = max(1, max(cfg.init_pulls, 2) // cfg.pulls_per_round) \
+        * cfg.pulls_per_round
+    init = queries.shape[0] * idx.store.n_live * T0 * cfg.block
+    assert counted > 0
+    assert counted + init == pytest.approx(float(np.sum(res.coord_ops)))
+
+
+def test_plane_step_self_time_within_its_duration():
+    idx, queries = _dense_index()
+    obs = ObsContext("t")
+    plane = RequestPlane(idx, obs=obs)
+    plane.query(queries, rng=jax.random.PRNGKey(1), cache="bypass")
+    steps = _events(obs, "plane.step", plane.plane_id)
+    assert steps
+    for e in steps:
+        a = e["attrs"]
+        assert 0.0 <= a["self_ms"] <= a["dur_ms"]
+        assert a["dur_ms"] == pytest.approx(e["dur"] * 1e3)
+    # the step that launched the group paid its race.init
+    init = _events(obs, "race.init")
+    assert len(init) == 1
+    assert any(e["attrs"]["dur_ms"] - e["attrs"]["self_ms"]
+               >= init[0]["attrs"]["dur_ms"] for e in steps)
+    assert _events(obs, "plane.admission", plane.plane_id)
+    assert _events(obs, "plane.harvest", plane.plane_id)
+
+
+def test_disabled_obs_context_records_no_events():
+    idx, queries = _dense_index()
+    obs = ObsContext("off", enabled=False)
+    plane = RequestPlane(idx, obs=obs)
+    t = plane.query(queries, rng=jax.random.PRNGKey(1), cache="bypass")
+    assert t.reason == "certified"
+    assert len(obs.events) == 0 and obs.events.total == 0
+    assert isinstance(obs.tracer.annotate("x"), contextlib.nullcontext)
+    assert obs.tracer.span("x") is NULL_SPAN
+
+
+def test_profile_scopes_name_exactify_and_compaction():
+    """Trace-time scopes name the snapshot's exactify and the frontier
+    compaction in the compiled programs a device trace shows."""
+    from repro.index.anytime import _fused_partial
+    from repro.index.frontier import compact_frontier
+    idx, queries = _dense_index()
+    s = idx.race(queries, jax.random.PRNGKey(0), obs=ObsContext("t"))
+    partial = _fused_partial.lower(
+        s._x, s._qs, s._st, s._pool, cfg=s._cfg, d=s._d,
+        log_term=s._log_term, prior_weight=s._prior_weight)
+    assert "/repro.exactify/" in partial.compile().as_text()
+    compact = compact_frontier.lower(s._st, W_new=s._st.width // 2)
+    assert "/repro.compact_frontier/" in compact.compile().as_text()
+
+
+def _host_annotations(trace_dir):
+    from jax.profiler import ProfileData
+    import glob
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                           for ev in line.events
+                           if ev.name.startswith("repro."))
+    return out
+
+
+def test_profiler_capture_nests_race_and_plane_spans(tmp_path):
+    """On a CPU profiler capture of a tiny plane race, every phase of the
+    race loop and the plane is a host annotation inside its parent."""
+    idx, queries = _dense_index()
+    obs = ObsContext("t")
+    plane = RequestPlane(idx, obs=obs)
+    plane.query(queries, rng=jax.random.PRNGKey(1), cache="bypass")  # warm
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level, opts.host_tracer_level = 0, 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        plane.query(queries, rng=jax.random.PRNGKey(2), cache="bypass")
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_annotations(str(tmp_path))
+    names = {n for n, _, _ in spans}
+    assert {"repro.race.sync", "repro.race.launch", "repro.race.summary",
+            "repro.race.init", "repro.race.epoch.fused", "repro.plane.step",
+            "repro.plane.admission", "repro.plane.harvest"} <= names
+    parents = {"repro.race.launch": ("repro.race.epoch.fused",),
+               "repro.race.sync": ("repro.race.summary",
+                                   "repro.race.epoch.fused"),
+               "repro.race.summary": ("repro.race.init",
+                                      "repro.race.epoch.fused"),
+               "repro.race.init": ("repro.plane.admission",),
+               "repro.race.epoch.fused": ("repro.plane.step",),
+               "repro.plane.admission": ("repro.plane.step",),
+               "repro.plane.harvest": ("repro.plane.step",)}
+
+    def inside(span, parent_names):
+        _, s, e = span
+        return any(n in parent_names and ps <= s and e <= pe
+                   for n, ps, pe in spans)
+
+    for span in spans:
+        if span[0] in parents:
+            assert inside(span, parents[span[0]]), span[0]
 
 
 # ---------------------------------------------------------------------------

@@ -124,3 +124,36 @@ def test_wide_init_step_fits_one_chip(one_chip):
                           impl="kernel", prior_weight=4.0).compile()
     assert "tpu_custom_call" in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 2**28
+
+
+def test_init_and_epoch_pulls_keep_the_kernel_name(one_chip):
+    """The wide init's launch and the epoch's are told apart by their
+    scopes, while both custom calls keep the Pallas kernel's instruction
+    name, ``fused_epoch_pull``, that the benchmark's roofline sums."""
+    import re
+    from repro.configs.bmo_nn import DENSE
+    from repro.index.batched_race import _fused_epoch_step, _fused_init
+    s = functools.partial(_spec, one_chip)
+    cap, d, q = 1024, 1024, 8
+    init_args = (s((cap, d)), s((q, d)), s((cap,), jnp.bool_), s((cap,)),
+                 s((2,), jnp.uint32))
+    init_kw = dict(cfg=DENSE.bmo, block=BLOCK, impl="kernel",
+                   prior_weight=4.0)
+    st, pool = jax.eval_shape(functools.partial(_fused_init, **init_kw),
+                              *init_args)
+    st = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), st)
+    texts = {
+        "repro.fused_init_pull":
+            _fused_init.lower(*init_args, **init_kw).compile().as_text(),
+        "repro.fused_epoch_pull": _fused_epoch_step.lower(
+            s((cap, d)), s((q, d)), st, s(pool.shape), cfg=DENSE.bmo,
+            block=BLOCK, d=d, impl="kernel", eliminate=True,
+            prior_weight=4.0, log_term=10.0, T=8).compile().as_text()}
+    for scope, text in texts.items():
+        calls = [ln for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        names = {re.sub(r"(\.\d+)+$", "", ln.split(" = ", 1)[0].strip()
+                        .lstrip("%")) for ln in calls}
+        assert names == {"fused_epoch_pull"}, names
+        assert f"/{scope}/" in text
+    assert "repro.fused_epoch_pull" not in texts["repro.fused_init_pull"]
