@@ -96,8 +96,45 @@ class Partial(NamedTuple):
 
 def _to_host(summ: RaceSummary) -> Partial:
     # THE per-epoch device->host boundary: one deliberate fetch of the
-    # whole summary; everything downstream is host-resident numpy.
+    # whole summary; everything downstream is host-resident numpy. The
+    # fused session's epoch hands it arrays its one packed fetch already
+    # brought over (``_unpack_epoch``), which pass through untouched.
     return Partial(*host_fetch(tuple(summ)))
+
+
+#: host dtype of each ``RaceSummary`` field in the fused epoch's packed
+#: buffer, then the survivor count (int32); ids, values and ci are (Q, k),
+#: the rest (Q,): (Q, 3k + 7) int32 words in all
+_PACKED = RaceSummary(ids=np.int32, values=np.float32, ci=np.float32,
+                      acc_count=np.int32, cand_lcb_min=np.float32,
+                      done=np.bool_, coord_ops=np.float32, rounds=np.int32,
+                      n_exact=np.int32)
+
+
+def _pack_epoch(summ: RaceSummary, n_surv) -> jax.Array:
+    """On the device: the summary and the survivor count as one (Q, 3k + 7)
+    int32 array, float32 fields bit-cast, so the host fetches them in one
+    transfer."""
+    cols = []
+    for a, dt in zip((*summ, n_surv), (*_PACKED, np.int32)):
+        a = a.astype(dt).reshape(a.shape[0], -1)
+        cols.append(jax.lax.bitcast_convert_type(a, jnp.int32)
+                    if dt == np.float32 else a.astype(jnp.int32))
+    return jnp.concatenate(cols, axis=1)
+
+
+def _unpack_epoch(buf: np.ndarray, k: int):
+    """On the host: ``(RaceSummary of numpy arrays, n_surv)`` from a fetched
+    ``_pack_epoch`` buffer, each array as ``_to_host`` would have fetched it
+    on its own."""
+    out, at = [], 0
+    for i, dt in enumerate((*_PACKED, np.int32)):
+        w = k if i < 3 else 1
+        col = np.ascontiguousarray(buf[:, at:at + w])
+        at += w
+        col = col.view(np.float32) if dt == np.float32 else col.astype(dt)
+        out.append(col if i < 3 else col[:, 0])
+    return RaceSummary(*out[:-1]), out[-1]
 
 
 def _summarize(ids, mean, ci, exact, accepted, rejected, valid, done,
@@ -209,6 +246,27 @@ def _fused_partial(x, qs, st: FrontierState, prior_pool, *, cfg: BMOConfig,
                       st.rejected, st.valid, st.done, st.coord_ops,
                       st.rounds, st.n_exact, cfg.k)
     return st, summ
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "step", "cfg", "block", "d", "impl", "eliminate", "prior_weight",
+    "log_term", "T"))
+def _fused_epoch_snapshot(x, qs, st: FrontierState, prior_pool, *, step,
+                          cfg: BMOConfig, block: int, d: int, impl: str,
+                          eliminate: bool, prior_weight: float,
+                          log_term: float, T: int):
+    """One epoch of the fused session in one launch: ``step`` (the epoch
+    step, ``_fused_epoch_step``), then the snapshot's exactify and summary
+    (``_fused_partial``) on the state it produced. Returns the exactified
+    state, the summary and survivor count packed by ``_pack_epoch``, and
+    the survivor count. ``step`` is static, so a launch traces whatever
+    step the module holds when it launches."""
+    st, n_surv, _ = step(x, qs, st, prior_pool, cfg=cfg, block=block, d=d,
+                         impl=impl, eliminate=eliminate,
+                         prior_weight=prior_weight, log_term=log_term, T=T)
+    st, summ = _fused_partial(x, qs, st, prior_pool, cfg=cfg, d=d,
+                              log_term=log_term, prior_weight=prior_weight)
+    return st, _pack_epoch(summ, n_surv), n_surv
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "d", "eliminate",
@@ -369,7 +427,8 @@ class RaceSession:
     snapshot arrays the drivers already transferred — the epoch's pull /
     coord-op deltas, frontier width, survivors, the CI radius of the worst
     uncertified position, the time blocked on the device (``wait_ms``, every
-    fetch goes through ``_fetch``) and the rest (``host_ms``), and (sharded)
+    fetch goes through ``_fetch``), the number of those fetches
+    (``fetches``) and the rest (``host_ms``), and (sharded)
     the per-shard straggler split, as a ``race.epoch`` span under the
     session's ``sid`` trace id plus registry metrics (DESIGN.md §8.3). The
     session's start is a ``race.init`` span. Jitted code is untouched.
@@ -386,6 +445,7 @@ class RaceSession:
         self.last_epoch: Optional[dict] = None
         self.race_s = 0.0     # wall time inside race.init and race.epoch
         self._wait_s = 0.0    # this epoch's time blocked in _fetch
+        self._fetches = 0     # this epoch's _fetch calls
         self.shard_coord_ops: Optional[np.ndarray] = None
         self.shard_rounds: Optional[np.ndarray] = None
         self._snap: Optional[Partial] = None
@@ -450,6 +510,7 @@ class RaceSession:
         with self.obs.tracer.span("race.sync", trace=self.sid):
             value = fetch(value)
         self._wait_s += time.perf_counter() - t0
+        self._fetches += 1
         return value
 
     @contextlib.contextmanager
@@ -479,7 +540,7 @@ class RaceSession:
                                                       float)
                 # host-sync: post-boundary numpy
                 self._prev_shard_rounds = np.array(self.shard_rounds, float)
-        self._wait_s = 0.0
+        self._wait_s, self._fetches = 0.0, 0
         t0 = time.perf_counter()
         with self.obs.tracer.annotate(f"race.epoch.{self.kind}"):
             alive = self._step_impl()
@@ -533,7 +594,8 @@ class RaceSession:
                       kind=self.kind).observe(dur * 1e3)
         self.obs.tracer.complete("race.epoch", t0, dur, trace=self.sid,
                                  dur_ms=dur * 1e3, wait_ms=self._wait_s * 1e3,
-                                 host_ms=(dur - self._wait_s) * 1e3, **info)
+                                 host_ms=(dur - self._wait_s) * 1e3,
+                                 fetches=self._fetches, **info)
 
     def _epoch_extra(self) -> dict:
         """Per-box epoch attributes (frontier width, survivors, R)."""
@@ -561,7 +623,10 @@ class RaceSession:
 class FusedSession(RaceSession):
     """Single-shard dense/rotated: the §4 epoch-fused survivor-compacted
     driver, host loop exposed one epoch at a time (same compaction schedule
-    and adaptive-R rule as the blocking ``fused_race_topk``)."""
+    and adaptive-R rule as the blocking ``fused_race_topk``). An epoch is
+    one launch (``_fused_epoch_snapshot``: the step, the exactify and the
+    summary) and one fetch of one packed array; ``_refresh`` serves the
+    session's start."""
 
     kind = "fused"
 
@@ -614,9 +679,10 @@ class FusedSession(RaceSession):
                 "R": self._last_R}
 
     def _epoch_launch(self, R: int):
-        return _fused_epoch_step, (self._x, self._qs, self._st, self._pool), \
-            dict(cfg=self._cfg, block=self._block, d=self._d,
-                 impl=self._impl, eliminate=self._eliminate,
+        return _fused_epoch_snapshot, \
+            (self._x, self._qs, self._st, self._pool), \
+            dict(step=_fused_epoch_step, cfg=self._cfg, block=self._block,
+                 d=self._d, impl=self._impl, eliminate=self._eliminate,
                  prior_weight=self._prior_weight, log_term=self._log_term,
                  T=R * self._cfg.pulls_per_round)
 
@@ -636,12 +702,13 @@ class FusedSession(RaceSession):
             if W_new < self._st.width:
                 self._st = compact_frontier(self._st, W_new=W_new)
             fn, args, kwargs = self._epoch_launch(R)
-            st, n_surv, _ = fn(*args, **kwargs)
+            self._st, packed, _ = fn(*args, **kwargs)
         self._rounds_spent += R
         self._last_R = R
-        self._n_surv = self._fetch(n_surv)
+        # one fetch an epoch: the snapshot and the next epoch's survivors
+        summ, self._n_surv = _unpack_epoch(self._fetch(packed), self.k)
+        self._snap = _to_host(summ)
         self.epochs += 1
-        self._refresh(st)
         return not self.done.all()
 
 

@@ -81,12 +81,88 @@ def test_session_epoch_hlo_is_the_stepped_launch():
     lowered = index.race(queries, jax.random.PRNGKey(1), impl="ref")
     plain = index.race(queries, jax.random.PRNGKey(1), impl="ref")
     hlo = lowered.epoch_hlo()
-    assert hlo.startswith("HloModule jit__fused_epoch_step")
+    assert hlo.startswith("HloModule jit__fused_epoch_snapshot")
+    # one program: the epoch's pull, then the snapshot's exactify
+    assert "/repro.fused_epoch_pull/" in hlo and "/repro.exactify/" in hlo
     for s in (lowered, plain):
         while s.step():
             pass
     for a, b in zip(lowered.snapshot, plain.snapshot):
         np.testing.assert_array_equal(a, b)
+
+
+def _fused_session(metric, seed=7):
+    from repro.api import Index
+    # 128 blocks a row, as the dense set has: arms are accepted on
+    # estimates, so the snapshot's exactify has work in every race
+    corpus, queries = make_knn_benchmark_data("dense", 256, 2048, 4,
+                                              seed=seed)
+    cfg = BMOConfig(k=3, delta=0.01, block=16, batch_arms=16,
+                    pulls_per_round=2, metric=metric,
+                    rotate=metric == "l2")
+    index = Index.build(corpus, cfg, jax.random.PRNGKey(0))
+    return lambda: index.race(queries, jax.random.PRNGKey(1), impl="ref")
+
+
+@pytest.mark.parametrize("metric", ["l2", "l1"],
+                         ids=["rotated_l2", "l1"])
+def test_fused_epoch_snapshot_matches_step_then_partial(metric):
+    """An epoch of one launch gives, epoch by epoch, the snapshot and the
+    survivor count of the epoch step followed by the snapshot program, on
+    the same schedule of widths and rounds."""
+    from repro.index.anytime import _fused_partial, _to_host
+    from repro.index.batched_race import _fused_epoch_step
+    from repro.index.frontier import compact_frontier
+    from repro.utils.hostsync import host_fetch
+    new_session = _fused_session(metric)
+    s, ref = new_session(), new_session()
+    assert s.kind == "fused"
+    for a, b in zip(s.snapshot, ref.snapshot):
+        np.testing.assert_array_equal(a, b)
+    epochs, alive = 0, True
+    while alive:
+        alive = s.step()
+        epochs += 1
+        # the parent composition, at the width and rounds the session took
+        if s._st.width < ref._st.width:
+            ref._st = compact_frontier(ref._st, W_new=s._st.width)
+        st, n_surv, _ = _fused_epoch_step(
+            ref._x, ref._qs, ref._st, ref._pool, cfg=ref._cfg,
+            block=ref._block, d=ref._d, impl=ref._impl,
+            eliminate=ref._eliminate, prior_weight=ref._prior_weight,
+            log_term=ref._log_term, T=s._last_R * ref._cfg.pulls_per_round)
+        ref._st, summ = _fused_partial(
+            ref._x, ref._qs, st, ref._pool, cfg=ref._cfg, d=ref._d,
+            log_term=ref._log_term, prior_weight=ref._prior_weight)
+        want = _to_host(summ)
+        for a, b in zip(s.snapshot, want):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(a, b)
+        n_surv = host_fetch(n_surv)
+        assert s._n_surv.dtype == n_surv.dtype
+        np.testing.assert_array_equal(s._n_surv, n_surv)
+    assert epochs > 1
+    assert s.done.all()
+
+
+def test_fused_epoch_snapshot_state_has_the_snapshot_programs_avals():
+    """The epoch program's state is the snapshot program's, aval for aval
+    (shape, dtype, weak type): a warm-up that feeds either one back into
+    the epoch program compiles what the serving loop runs."""
+    from repro.index.anytime import _fused_partial
+    s = _fused_session("l2")()
+    fn, args, kwargs = s._epoch_launch(s._R0)
+    st, _, _ = jax.eval_shape(lambda *a: fn(*a, **kwargs), *args)
+    want, _ = jax.eval_shape(lambda *a: _fused_partial(
+        *a, cfg=s._cfg, d=s._d, log_term=s._log_term,
+        prior_weight=s._prior_weight), *args)
+    aval = lambda a: (a.shape, a.dtype, a.weak_type)
+    assert (jax.tree_util.tree_map(aval, st)
+            == jax.tree_util.tree_map(aval, want))
+    # and the state the session starts from is the same again
+    assert (jax.tree_util.tree_map(aval, st)
+            == jax.tree_util.tree_map(
+                lambda a: aval(jax.typeof(a)), s._st))
 
 
 def test_batched_parity_sparse():

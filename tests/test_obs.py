@@ -566,11 +566,34 @@ def test_race_epoch_splits_into_wait_and_host():
         assert a["wait_ms"] > 0.0 and a["host_ms"] > 0.0
         assert a["wait_ms"] + a["host_ms"] == pytest.approx(a["dur_ms"])
         assert a["dur_ms"] == pytest.approx(e["dur"] * 1e3)
-    # two fetches an epoch (survivors, snapshot), each a race.sync span
+    # one fetch an epoch (snapshot and survivors packed), a race.sync span
     sync = _events(obs, "race.sync", s.sid)
-    assert len(sync) == 2 * len(race) + 1          # + the init's snapshot
+    assert len(sync) == len(race) + 1              # + the init's snapshot
     assert len(_events(obs, "race.launch", s.sid)) == len(race)
-    assert len(_events(obs, "race.summary", s.sid)) == len(race) + 1
+    # the snapshot program runs on its own only at the session's start
+    summary = _events(obs, "race.summary", s.sid)
+    init = _events(obs, "race.init", s.sid)
+    assert len(summary) == 1
+    assert (init[0]["ts"] <= summary[0]["ts"] and summary[0]["ts"]
+            + summary[0]["dur"] <= init[0]["ts"] + init[0]["dur"])
+
+
+@pytest.mark.parametrize("kind", ["dense", "sparse"])
+def test_each_epoch_is_one_launch_and_one_fetch(kind):
+    """Every epoch of a single-shard session dispatches its launch inside
+    one race.launch span and fetches once, inside that epoch's span."""
+    obs = ObsContext("t")
+    s = _raced(obs, kind)
+    race = _events(obs, "race.epoch", s.sid)
+    assert race and s.epochs == len(race)
+    for e in race:
+        assert e["attrs"]["fetches"] == 1
+        lo, hi = e["ts"], e["ts"] + e["dur"]
+        inside = lambda name: [x for x in _events(obs, name, s.sid)
+                               if lo <= x["ts"] and x["ts"] + x["dur"] <= hi]
+        assert len(inside("race.sync")) == 1
+        assert len(inside("race.launch")) == 1
+        assert not inside("race.summary")
 
 
 @pytest.mark.parametrize("kind", ["dense", "sparse"])

@@ -130,7 +130,6 @@ def test_init_and_epoch_pulls_keep_the_kernel_name(one_chip):
     """The wide init's launch and the epoch's are told apart by their
     scopes, while both custom calls keep the Pallas kernel's instruction
     name, ``fused_epoch_pull``, that the benchmark's roofline sums."""
-    import re
     from repro.configs.bmo_nn import DENSE
     from repro.index.batched_race import _fused_epoch_step, _fused_init
     s = functools.partial(_spec, one_chip)
@@ -150,10 +149,41 @@ def test_init_and_epoch_pulls_keep_the_kernel_name(one_chip):
             block=BLOCK, d=d, impl="kernel", eliminate=True,
             prior_weight=4.0, log_term=10.0, T=8).compile().as_text()}
     for scope, text in texts.items():
-        calls = [ln for ln in text.splitlines()
-                 if 'custom_call_target="tpu_custom_call"' in ln]
-        names = {re.sub(r"(\.\d+)+$", "", ln.split(" = ", 1)[0].strip()
-                        .lstrip("%")) for ln in calls}
-        assert names == {"fused_epoch_pull"}, names
+        assert _kernel_names(text) == {"fused_epoch_pull"}
         assert f"/{scope}/" in text
     assert "repro.fused_epoch_pull" not in texts["repro.fused_init_pull"]
+
+
+def test_session_epoch_program_fits_one_chip(one_chip):
+    """A session's epoch at the paper's shape and the widest frontier: one
+    program runs the step's pull, the exactify and the summary. Its custom
+    call keeps the kernel's instruction name under the epoch's scope, and
+    its temporaries stay small beside the store."""
+    from repro.configs.bmo_nn import DENSE
+    from repro.index.anytime import _fused_epoch_snapshot
+    from repro.index.batched_race import _fused_epoch_step, _fused_init
+    s = functools.partial(_spec, one_chip)
+    x, qs = s((CAP, D_PAD)), s((Q, D_PAD))
+    st, pool = jax.eval_shape(functools.partial(
+        _fused_init, cfg=DENSE.bmo, block=BLOCK, impl="kernel",
+        prior_weight=4.0), x, qs, s((CAP,), jnp.bool_), s((CAP,)),
+        s((2,), jnp.uint32))
+    st = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), st)
+    c = _fused_epoch_snapshot.lower(
+        x, qs, st, s(pool.shape), step=_fused_epoch_step, cfg=DENSE.bmo,
+        block=BLOCK, d=12288, impl="kernel", eliminate=True,
+        prior_weight=4.0, log_term=10.0, T=T).compile()
+    text = c.as_text()
+    assert _kernel_names(text) == {"fused_epoch_pull"}
+    assert "/repro.fused_epoch_pull/" in text and "/repro.exactify/" in text
+    assert c.memory_analysis().temp_size_in_bytes < 2**28
+
+
+def _kernel_names(text):
+    """Instruction names of the Pallas custom calls in compiled HLO text,
+    without XLA's numeric suffixes."""
+    import re
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    return {re.sub(r"(\.\d+)+$", "", ln.split(" = ", 1)[0].strip()
+                   .lstrip("%")) for ln in calls}
