@@ -76,7 +76,9 @@ class PlaneEntry:
             fn, args, kwargs = s._epoch_launch(R)
             st, n_surv, _ = fn(*args, **kwargs)
             jax.block_until_ready(n_surv)
-            s._refresh(st)
+            # the epoch program's state is already exactified, as a
+            # serving epoch leaves it: no snapshot program to run
+            s._st = st
 
         launch(rounds(s._W0))           # the first epoch: every arm survives
         first = s._st
@@ -111,8 +113,7 @@ class PlaneEntry:
         return self.plane.active
 
     def step(self) -> None:
-        with self.annotate("bench.plane_step"):
-            self.plane.step()
+        self.plane.step()
 
     def poll(self, r: Request) -> bool:
         if r.handle is not None and r.handle.terminal:

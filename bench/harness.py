@@ -34,12 +34,13 @@ class Run:
     seconds: float
     t0: float                 # window open (time.monotonic())
     t1: float                 # window close: open + seconds, or (closed
-                              # loop) the last completion
-    requests: List[tr.Request]
+                              # loop) the loop's last look at the clock
+    requests: List[tr.Request]    # closed loop: those answered by the close
     setup_s: float
     peak_bytes: int
     events: list              # obs trace events logged in the window
     hist: dict                # histogram name -> (sum, count) in the window
+                              # (closed loop: both up to the close)
     trace: Optional[dict] = None    # trace.reduce(...) of a traced run
     peaks: Optional[dict] = None    # peaks.json entry of the device
 
@@ -227,27 +228,46 @@ def run_cell(workload: str, config: dict, mix: dict, metric_specs: list, *,
                               s.qid_of, annotate)
         else:
             win = tr.run_closed(entry, mix, seconds, s.qid_of)
-    jax.config.update("jax_log_compiles", False)
-    if trace:
-        jax.profiler.stop_trace()
-    reqs = win["requests"]
+    # what the metrics read stands as it did at the close
     in_window = int(compiles.value - setup_compiles)
-    log(f"window compiles {in_window} "
-        f"({compile_ms.sum / 1e3 - setup_compile_s:.3f} s)")
+    window_compile_s = compile_ms.sum / 1e3 - setup_compile_s
+    hist_after = _histograms(obs)
+    n_new = obs.events.total - events_before
+    events = obs.events.snapshot()[-n_new:] if n_new > 0 else []
+    reqs = win["requests"]
+    closed = mix["loop"] == "closed"
+    window_reqs = ([r for r in reqs if r.status != "pending"] if closed
+                   else reqs)
+    if trace:
+        t = time.monotonic()
+        jax.profiler.stop_trace()
+        log(f"stop_trace {time.monotonic() - t:.3f} s")
+    if closed:
+        # a closed loop's window ends at the close; the requests still in
+        # flight are answered untraced, for the check alone
+        t = time.monotonic()
+        with annotate("bench.drain"):
+            left = tr.drain(entry, win["inflight"],
+                            float(mix.get("drain_s", 60)))
+        log(f"drain: {len(win['inflight'])} requests in flight at the "
+            f"close, {len(left)} unanswered after {time.monotonic() - t:.3f}"
+            f" s; {int(compiles.value - setup_compiles) - in_window} "
+            f"compiles")
+    jax.config.update("jax_log_compiles", False)
+    log(f"window compiles {in_window} ({window_compile_s:.3f} s)")
     if mix["loop"] == "open" and reqs:
         late = np.array([r.submitted - r.intended for r in reqs]) * 1e3
         log(f"generator lateness ms: mean {late.mean():.3f} "
             f"max {late.max():.3f} over {len(reqs)} requests")
     peak = _device_peak()
-    hist_after = _histograms(obs)
     hist = {name: (s - hist_before.get(name, (0.0, 0))[0],
                    c - hist_before.get(name, (0.0, 0))[1])
             for name, (s, c) in hist_after.items()}
-    n_new = obs.events.total - events_before
-    events = obs.events.snapshot()[-n_new:] if n_new > 0 else []
     if not control:
-        log(f"largest group {max(group_rows(events).values(), default=0)} "
-            f"rows (warm batches {s.sizes})")
+        rows = sorted(group_rows(events).values())
+        log(f"largest group {max(rows, default=0)} rows (warm batches "
+            f"{s.sizes}); groups by rows "
+            f"{ {r: rows.count(r) for r in sorted(set(rows))} }")
     if index is not None:
         ms = _scan_ms(index.store.x, rows_per, k)
         log(f"exact scan over the resident store: {ms:.4f} ms per request "
@@ -286,8 +306,8 @@ def run_cell(workload: str, config: dict, mix: dict, metric_specs: list, *,
     correct = all(c["value"] <= c["limit"] for c in checks.values())
 
     run = Run(workload=workload, config=config, mix=mix, seconds=seconds,
-              t0=win["t0"], t1=win["t1"], requests=reqs, setup_s=setup_s,
-              peak_bytes=peak, events=events, hist=hist)
+              t0=win["t0"], t1=win["t1"], requests=window_reqs,
+              setup_s=setup_s, peak_bytes=peak, events=events, hist=hist)
     device = _device_info(peak)
     if trace:
         from bench.trace import find_xplane, reduce_trace

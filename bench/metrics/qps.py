@@ -1,8 +1,6 @@
-"""qps: query rows certified in the window per second of the window.
-
-Open loop: rows certified by the close, over the window's seconds, so a
-growing backlog lowers it. Closed loop: every row, over the time to the
-last completion."""
+"""qps: query rows certified by the window's close, over the window's
+seconds. The same in both loops: an open loop's backlog lowers it, and a
+closed loop's drain after the close is left out of it."""
 
 
 def read(run):

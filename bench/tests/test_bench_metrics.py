@@ -43,6 +43,18 @@ def test_latency_from_intended_arrival_over_answered_requests():
     assert read("latency_p87_ms", run) == pytest.approx(np.percentile(lat, 87))
 
 
+def test_closed_median_latency_reads_the_requests_answered_by_the_close():
+    """``latency_p50_ms.closed`` is the median of the closed window's
+    requests, each sent when its client's last one finished: it can move
+    apart from the mean that Little's law ties to ``qps``."""
+    lat = [0.5, 0.6, 0.7, 4.0, 9.0]
+    reqs = [_req(i, float(i), float(i) + v) for i, v in enumerate(lat)]
+    run = _run(reqs)
+    assert read("latency_p50_ms.closed", run) == pytest.approx(700.0)
+    assert np.mean(lat) * 1e3 > 2 * read("latency_p50_ms.closed", run)
+    assert read("latency_p50_ms.closed", _run([])) is None
+
+
 def test_setup_and_peak():
     run = _run([])
     assert read("setup_s", run) == 42.0
@@ -79,7 +91,36 @@ def test_trace_metrics_need_a_trace():
                  "op_s": {"fused_epoch_pull": 0.5, "fusion.3": 1.0}}
     run.peaks = spec.peaks("TPU v5 lite")
     # 0.002 s of HBM time needed over 0.5 s of kernel time
-    assert read("fused_epoch_pull_roofline.closed", run) == pytest.approx(0.4)
+    assert read("fused_epoch_pull_roofline.open", run) == pytest.approx(0.4)
     assert read("device_idle.open", run) == pytest.approx(25.0)
     run.trace["op_s"] = {"fusion.3": 1.0}
     assert read("fused_epoch_pull_roofline.open", run) is None
+
+
+def test_closed_roofline_counts_the_work_of_spans_ended_by_the_close():
+    """A closed loop's roofline counts the ``coord_ops`` of the race spans
+    that ended in the window, not the answered requests' results: the
+    requests in flight at the close had device time in the trace too."""
+    per_s = 819e9 / 4                  # coordinates one second of HBM reads
+    events = [
+        {"name": "race.init", "attrs": {"coord_ops": per_s * 0.001}},
+        {"name": "race.epoch", "attrs": {"coord_ops": per_s * 0.0005}},
+        {"name": "race.epoch", "attrs": {"coord_ops": per_s * 0.0005}},
+        {"name": "race.sync", "attrs": {}},
+        {"name": "plane.admit", "attrs": {"session": "s-1", "rows": 2}}]
+    # one request answered by the close, whose results hold a tenth of
+    # the work
+    run = _run([_req(0, 0, 1, coord=per_s * 0.0002)], events=events)
+    run.trace = {"window_s": 4.0, "busy_s": 3.0,
+                 "op_s": {"fused_epoch_pull": 0.5}}
+    run.peaks = spec.peaks("TPU v5 lite")
+    # 0.002 s of HBM time over 0.5 s of kernel time, as the spans say
+    assert read("fused_epoch_pull_roofline.closed", run) == pytest.approx(
+        0.4)
+    assert read("fused_epoch_pull_roofline.open", run) == pytest.approx(
+        0.04)
+    assert read("fused_epoch_pull_roofline.closed",
+                _run(run.requests, events=events[3:], trace=run.trace,
+                     peaks=run.peaks)) is None
+    assert read("fused_epoch_pull_roofline.closed",
+                _run(run.requests, events=events)) is None

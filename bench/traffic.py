@@ -11,7 +11,8 @@ A mix (``traffic/<name>.json``)::
      "queries": {"kind": "pool", "size": 64},
      "entry": "plane",                # RequestPlane
      "plane": {"max_group_queries": 8},    # PlaneConfig over its defaults
-     "drain_s": 60}                   # wait past the close for answers
+     "drain_s": 60}                   # wait for answers past the close
+                                      # (closed loop: from the drain's start)
 
 Every run offers the same work at the same times. The queries are a
 fixed pool drawn from the configuration's data (``corpus.Generator``) and
@@ -26,8 +27,9 @@ arrival, so a loop that falls behind shows as latency, and the generator's
 own lateness (submit minus intended arrival) is reported beside it.
 
 Closed loop: each client sends its next request when its last one has
-finished; none is sent after the window's close, and the requests in
-flight are drained.
+finished, and none is sent after the window's close. The window ends at
+the close: ``run_closed`` returns there with the requests still in
+flight, which ``drain`` then answers for the check alone.
 """
 from __future__ import annotations
 
@@ -153,31 +155,41 @@ def run_open(entry, mix: dict, seconds: float, order: int, qid_of: Callable,
 
 
 def run_closed(entry, mix: dict, seconds: float, qid_of: Callable) -> dict:
-    """Keep ``clients`` requests outstanding until the close, then drain;
-    the window ends at the last completion."""
+    """Keep ``clients`` requests outstanding until the close, the first
+    look at the clock at or past ``seconds`` after the open, and return
+    there: the window's requests and times, and (``inflight``) those not
+    answered by the close."""
     clients = int(mix.get("clients", 1))
     rows = int(mix.get("rows_per_request", 1))
-    drain = float(mix.get("drain_s", 60))
     reqs: List[Request] = []
     inflight: dict = {}
     t0 = time.monotonic()
     while True:
         now = time.monotonic()
-        if now < t0 + seconds:
-            for c in range(clients):
-                if c not in inflight:
-                    r = Request(len(reqs), qid_of(len(reqs) * rows, rows), now)
-                    entry.submit(r)
-                    reqs.append(r)
-                    if r.status == "pending":
-                        inflight[c] = r
+        if now >= t0 + seconds:
+            break
+        for c in range(clients):
+            if c not in inflight:
+                r = Request(len(reqs), qid_of(len(reqs) * rows, rows), now)
+                entry.submit(r)
+                reqs.append(r)
+                if r.status == "pending":
+                    inflight[c] = r
         if entry.active:
             entry.step()
             inflight = {c: r for c, r in inflight.items() if entry.poll(r)}
-        if not inflight and now >= t0 + seconds:
-            break
-        if now > t0 + seconds + drain:
-            break
-    done = [r.finished for r in reqs if r.finished is not None]
-    return {"t0": t0, "t1": max(done) if done else time.monotonic(),
-            "requests": reqs, "end": time.monotonic()}
+    return {"t0": t0, "t1": now, "requests": reqs,
+            "inflight": list(inflight.values())}
+
+
+def drain(entry, pending: List[Request], seconds: float) -> List[Request]:
+    """Step ``entry`` until every request of ``pending`` is answered, or
+    for ``seconds`` from the drain's start; return those still pending.
+    The deadline starts here and not at the close: a traced run's
+    ``stop_trace`` holds the host for a minute or more in between."""
+    end = time.monotonic() + seconds
+    while True:
+        pending = [r for r in pending if entry.poll(r)]
+        if not pending or not entry.active or time.monotonic() >= end:
+            return pending
+        entry.step()
