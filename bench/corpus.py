@@ -17,7 +17,9 @@ unit ℓ2 norm, so that ℓ2 ranks as cosine does.
 """
 from __future__ import annotations
 
+import collections
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -120,17 +122,23 @@ class Generator:
                      normalize=self._normalize, **self._kw)
         return np.asarray(jax.device_get(q), np.float32)
 
-    def source(self) -> "RowSource":
-        return RowSource(self)
+    def source(self, log=lambda msg: None) -> "RowSource":
+        return RowSource(self, log)
 
 
 class RowSource:
     """The (n, d) corpus as ``Index.build`` reads it: ``.shape`` and row
     slices that are drawn on the device when asked for, so the unrotated
-    corpus is never resident whole."""
+    corpus is never resident whole. A build that needs the whole corpus on
+    the host (the sharded one) gets it from ``__array__``, drawn block by
+    block on the device and logged with its bytes and seconds."""
 
-    def __init__(self, gen: Generator):
+    #: rows of one block that ``__array__`` draws on a device
+    HOST_ROWS = 4096
+
+    def __init__(self, gen: Generator, log=lambda msg: None):
         self._gen = gen
+        self._log = log
         self.shape = (gen.n, gen.d)
 
     def __len__(self) -> int:
@@ -143,6 +151,32 @@ class RowSource:
         if step != 1:
             raise ValueError("a RowSource is read by contiguous row slices")
         return self._gen.rows(jnp.arange(start, stop, dtype=jnp.int32))
+
+    def __array__(self, dtype=None, copy=None):
+        """The whole corpus as one float32 host array. Blocks are drawn on
+        the local devices in turn and copied back while the next ones are
+        drawn, one block a device in flight."""
+        n, d = self.shape
+        out = np.empty((n, d), np.float32)
+        devs = jax.local_devices()
+        t = time.monotonic()
+        with jax.profiler.TraceAnnotation("bench.host_corpus"):
+            flight = collections.deque()
+            for i, start in enumerate(range(0, n, self.HOST_ROWS)):
+                stop = min(n, start + self.HOST_ROWS)
+                with jax.default_device(devs[i % len(devs)]):
+                    x = self._gen.rows(jnp.arange(start, stop,
+                                                  dtype=jnp.int32))
+                x.copy_to_host_async()
+                flight.append((start, stop, x))
+                if len(flight) > len(devs):
+                    a, b, x = flight.popleft()
+                    out[a:b] = np.asarray(x)
+            for a, b, x in flight:
+                out[a:b] = np.asarray(x)
+        self._log(f"host corpus: {out.nbytes} bytes drawn to the host in "
+                  f"{time.monotonic() - t:.3f} s")
+        return out if dtype is None else out.astype(dtype, copy=False)
 
 
 def row_blocks(gen: Generator, rows_per_block: int):

@@ -24,6 +24,30 @@ import numpy as np
 from bench.traffic import Request
 
 
+def reachable_rounds(R0: int, R_cap: int, W0: int, w: int, *, floor: int,
+                     shards: int = 1) -> list:
+    """Every rounds value a race session can launch at frontier width
+    ``w``, ascending. A session takes min(R0 · pow2_floor(S·W0 // need),
+    R_cap), where ``need`` is its most survivors of a query (S = 1) or,
+    sharded, the sum over its S shards of each shard's most. Below W0
+    (and at the floor) ``need`` is anything from 1 to S·w; at W0 above the
+    floor the frontier stays only while some shard keeps over half its
+    arms, so ``need`` is over W0/2: one chip reaches only R0 there, S
+    shards R0 · 2^m for each 2^m < 2S (R0, 2·R0 and 4·R0 with four).
+    Below W0 both reach the same R0 · 2^m chain, capped at R_cap
+    (``tests/test_bench_sharded.py``)."""
+    from repro.index.frontier import pow2_floor
+
+    def rounds(need: int) -> int:
+        return min(R0 * pow2_floor(shards * W0 // max(need, 1)), R_cap)
+
+    top = shards * w
+    needs = {1 << j for j in range(top.bit_length())} | {top}
+    if w == W0 and w > floor:
+        needs = {t for t in needs if t > W0 // 2} | {W0 // 2 + 1}
+    return sorted({rounds(t) for t in needs})
+
+
 class PlaneEntry:
     CACHE = "bypass"
 
@@ -49,52 +73,72 @@ class PlaneEntry:
         """Launch once every (width, rounds) pair that a plane race of this
         batch's padded size can reach, from the states it would reach them
         by. The rounds of a launch are a static argument of the epoch step,
-        and a session takes them from its survivors:
-        min(R0 · pow2_floor(W0 // need), R_cap), where ``need``, the most
-        survivors of a query, is at most the width W once W is below W0.
-        The width halves at most once an epoch. So a race whose survivors
-        fall fast enters a width with more rounds than one whose survivors
-        fall slowly, and which pairs a race meets depends on its queries
-        and its seed: one warm race left others to compile inside the
-        window (single queries at widths 65536, 32768 and 16384 of the
-        dense set, on the chip). This walks the halving chain once for
-        each number of rounds, from the state after the first epoch."""
+        and a session takes them from its survivors
+        (``reachable_rounds``). The width halves at most once an epoch. So
+        a race whose survivors fall fast enters a width with more rounds
+        than one whose survivors fall slowly, and which pairs a race meets
+        depends on its queries and its seed: one warm race left others to
+        compile inside the window (single queries at widths 65536, 32768
+        and 16384 of the dense set, on the chip). This walks the halving
+        chain once for each number of rounds, from the state after the
+        first epoch.
+
+        A sharded session (``ShardedFusedSession``) compacts every shard's
+        frontier at once (``_compact_stacked``), and each of its epochs
+        runs a second program after the step, the exactify and the merged
+        summary (``_refresh``), whose shape the width alone sets: that one
+        is launched once a width."""
         from repro.api import QuerySpec
         from repro.core.datasets import next_pow2
-        from repro.index.frontier import compact_frontier, pow2_floor
+        from repro.index.anytime import ShardedFusedSession
+        from repro.index.frontier import compact_frontier
+        from repro.index.sharded import _compact_stacked
 
         pad = next_pow2(len(batch)) - len(batch)
         rows = np.concatenate([batch, np.repeat(batch[:1], pad, 0)])
         # the plane's own call, with the spec its submit above made
         s = self.index.race(rows, rng, spec=QuerySpec(cache=self.CACHE),
                             raced_queries=len(batch))
+        sharded = isinstance(s, ShardedFusedSession)
+        compact = _compact_stacked if sharded else compact_frontier
+        summarised = set()
 
-        def rounds(need: int) -> int:
-            return min(s._R0 * pow2_floor(s._W0 // need), s._R_cap)
+        def reach(w: int) -> list:
+            return reachable_rounds(s._R0, s._R_cap, s._W0, w,
+                                    floor=s._floor_w,
+                                    shards=s._S if sharded else 1)
 
         def launch(R: int) -> None:
             fn, args, kwargs = s._epoch_launch(R)
             st, n_surv, _ = fn(*args, **kwargs)
             jax.block_until_ready(n_surv)
-            # the epoch program's state is already exactified, as a
-            # serving epoch leaves it: no snapshot program to run
-            s._st = st
+            w = st.ids.shape[-1]
+            if sharded and w not in summarised:
+                summarised.add(w)
+                s._refresh(st)
+            else:
+                # the epoch program's state is already exactified, as a
+                # serving epoch leaves it: no snapshot program to run
+                s._st = st
 
-        launch(rounds(s._W0))           # the first epoch: every arm survives
+        first_rounds, *more = reach(s._W0)
+        launch(first_rounds)            # the first epoch: every arm survives
         first = s._st
+        for R in more:                  # a shard keeps over half its arms
+            s._st = first
+            launch(R)
         widths, w = [], s._W0 // 2
         while w >= s._floor_w:
             widths.append(w)
             w //= 2
-        reach = {w: sorted({rounds(1 << j) for j in range(w.bit_length())})
-                 for w in widths}
+        reach_of = {w: reach(w) for w in widths}
         seen = set()
-        for r in sorted({r for rs in reach.values() for r in rs},
+        for r in sorted({r for rs in reach_of.values() for r in rs},
                         reverse=True):
             s._st = first
             for w in widths:
-                s._st = compact_frontier(s._st, W_new=w)
-                R = r if r in reach[w] else reach[w][0]
+                s._st = compact(s._st, W_new=w)
+                R = r if r in reach_of[w] else reach_of[w][0]
                 if (w, R) not in seen:
                     seen.add((w, R))
                     launch(R)

@@ -83,7 +83,9 @@ def _device_peak() -> int:
 
 def _scan_ms(store_x, rows: int, k: int) -> float:
     """Median device time of an exact scan over the resident store for one
-    request of ``rows`` queries: what the race has to beat."""
+    request of ``rows`` queries: what the race has to beat. A sharded
+    store's rows (``store_rows``) are scanned where they lie, each chip its
+    own shard."""
     import jax
     import jax.numpy as jnp
 
@@ -154,8 +156,9 @@ def setup(config: dict, mix: dict, seed: int, seconds: float, *,
         slot_row = np.arange(gen.n)
     else:
         with annotate("bench.build"):
-            index = Index.build(gen.source(), bmo, gen.stream(BUILD))
-            jax.block_until_ready(index.store.x)
+            index = Index.build(gen.source(log), bmo, gen.stream(BUILD),
+                                shards=spec.shards(config))
+            jax.block_until_ready(store_rows(index.store))
         slot_row = np.full((index.capacity,), -1, np.int64)
         slot_row[np.asarray(index.build_gids)] = np.arange(gen.n)
         entry = PlaneEntry(index, pool, gen.stream(RACE), k=bmo.k,
@@ -166,6 +169,14 @@ def setup(config: dict, mix: dict, seed: int, seconds: float, *,
     log(f"set-up: build {built:.3f} s, warm races "
         f"{time.monotonic() - t - built:.3f} s")
     return Setup(gen, bmo.k, pool, qid_of, entry, index, slot_row, sizes)
+
+
+def store_rows(store):
+    """The store's rows on the device: one array, or for a sharded store
+    the (S·stride, d_pad) array over the mesh, each shard's rows on its own
+    chip."""
+    return (store.device_arrays()["x"] if hasattr(store, "shards")
+            else store.x)
 
 
 def data_seed(config: dict) -> int:
@@ -269,9 +280,10 @@ def run_cell(workload: str, config: dict, mix: dict, metric_specs: list, *,
             f"{s.sizes}); groups by rows "
             f"{ {r: rows.count(r) for r in sorted(set(rows))} }")
     if index is not None:
-        ms = _scan_ms(index.store.x, rows_per, k)
-        log(f"exact scan over the resident store: {ms:.4f} ms per request "
-            f"of {rows_per} rows (median of 10)")
+        ms = _scan_ms(store_rows(index.store), rows_per, k)
+        log(f"exact scan over the resident store ({spec.shards(config)} "
+            f"shards): {ms:.4f} ms per request of {rows_per} rows (median "
+            f"of 10)")
     s.entry = s.index = entry = index = None
     gc.collect()
 

@@ -53,6 +53,11 @@ def main(argv=None) -> int:
     bench = spec.load(ROOT)
     cell = spec.cell(bench, args.workload)
     config = spec.config(bench, cell["config"], ROOT)
+    try:
+        spec.check_chips(cell, config)
+    except ValueError as e:
+        _err(str(e))
+        return 2
     mix = spec.traffic(cell["traffic"], ROOT)
     metric_specs = spec.metrics_for(bench, cell["name"], bool(args.trace))
     try:

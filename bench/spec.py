@@ -45,6 +45,20 @@ def config(bench: dict, name: str, root: str = ROOT) -> dict:
     raise KeyError(f"no configuration {name!r} in BENCHMARK.json")
 
 
+def shards(config: dict) -> int:
+    """Shards of the configuration's index, one a chip (1 when absent)."""
+    return int(config.get("shards", 1))
+
+
+def check_chips(cell: dict, config: dict) -> None:
+    """A cell needs a chip for each shard of its configuration's index:
+    the build places shard s on chip s."""
+    if int(cell["chips"]) < shards(config):
+        raise ValueError(f"cell {cell['name']!r} asks for {cell['chips']} "
+                         f"chips, fewer than the {shards(config)} shards of "
+                         f"its configuration {cell['config']!r}")
+
+
 def traffic(name: str, root: str = ROOT) -> dict:
     return _json(os.path.join(root, "bench", "traffic", f"{name}.json"))
 

@@ -17,6 +17,7 @@ import argparse
 import json
 import os
 import sys
+import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -45,6 +46,8 @@ def main(argv=None) -> int:
         print("sweep: no TPU found", file=sys.stderr)
         return 2
     use_compile_cache()
+    # as run.py: keep every program, so that the cell's runs find them
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     bench = spec.load(ROOT)
     cell = spec.cell(bench, args.workload)
     config = spec.config(bench, cell["config"], ROOT)
@@ -54,7 +57,12 @@ def main(argv=None) -> int:
         return 2
     rates = [float(r) for r in args.rates.split(",")]
     most = dict(mix, arrival=dict(mix["arrival"], rate_qps=max(rates)))
-    s = setup(config, most, args.seed, args.seconds)
+    t = time.monotonic()
+    s = setup(config, most, args.seed, args.seconds,
+              log=lambda msg: print(f"sweep: {msg}", file=sys.stderr,
+                                    flush=True))
+    print(f"sweep: set-up {time.monotonic() - t:.3f} s", file=sys.stderr,
+          flush=True)
     for i, rate in enumerate(rates):
         # every rate draws from the same query pool (the LRU is bypassed)
         m = dict(mix, arrival=dict(mix["arrival"], rate_qps=rate))

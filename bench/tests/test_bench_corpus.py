@@ -59,3 +59,36 @@ def test_normalised_rows_have_unit_norm():
         rtol=1e-5)
     np.testing.assert_allclose(np.linalg.norm(gen.queries(8), axis=1), 1.0,
                                rtol=1e-5)
+
+
+def test_row_source_as_an_array_is_its_slices(monkeypatch):
+    """``__array__`` (the sharded build's host copy) draws block by block,
+    a block a device in flight, and equals the slices put together."""
+    gen = Generator(TINY, 5)
+    logs = []
+    src = RowSource(gen, logs.append)
+    monkeypatch.setattr(RowSource, "HOST_ROWS", 300)
+    whole = np.asarray(src)
+    assert whole.dtype == np.float32 and whole.shape == src.shape
+    parts = np.concatenate([np.asarray(src[a:a + 300])
+                            for a in range(0, gen.n, 300)])
+    np.testing.assert_array_equal(whole, parts)
+    assert len(logs) == 1 and str(whole.nbytes) in logs[0]
+
+
+def test_one_chip_build_reads_slices_and_never_the_whole_corpus(
+        monkeypatch):
+    """``build_index`` reads ``.shape`` and row slices, so the cells on one
+    chip draw no host copy of the corpus."""
+    import jax
+
+    from repro.api import Index
+    from repro.configs.base import BMOConfig
+    calls = []
+    monkeypatch.setattr(RowSource, "__array__",
+                        lambda self, *a, **kw: calls.append(1))
+    gen = Generator(TINY, 5)
+    index = Index.build(gen.source(), BMOConfig(**TINY["bmo"]),
+                        jax.random.PRNGKey(0))
+    assert calls == []
+    assert index.store.x.shape[0] >= TINY["n"]

@@ -78,3 +78,42 @@ def test_epoch_wait_and_host_add_up_to_the_epoch():
     assert (read("epoch_wait_ms.open", run)
             + read("epoch_host_ms.open", run)) == pytest.approx(
         sum(epochs) / len(epochs))
+
+
+def _sharded_epoch(shard_coord_ops, fetches=2):
+    return {"ph": "X", "name": "race.epoch", "trace": "s-2", "dur": 0.008,
+            "attrs": {"wait_ms": 6.0, "host_ms": 2.0, "dur_ms": 8.0,
+                      "fetches": fetches, "shards": len(shard_coord_ops),
+                      "coord_ops": float(sum(shard_coord_ops)),
+                      "shard_coord_ops": shard_coord_ops}}
+
+
+@pytest.mark.parametrize("epochs,want", [
+    ([[5.0, 5.0, 5.0, 5.0]], 1.0),
+    ([[8.0, 0.0, 0.0, 0.0]], 4.0),
+    # Σ max / Σ mean: (4 + 8) / (2.5 + 2) over two epochs
+    ([[4.0, 3.0, 2.0, 1.0], [8.0, 0.0, 0.0, 0.0]], 12.0 / 4.5),
+    # an epoch with no work weighs nothing
+    ([[0.0, 0.0, 0.0, 0.0], [6.0, 2.0, 2.0, 2.0]], 6.0 / 3.0),
+])
+def test_shard_straggle_is_the_slowest_shard_over_the_mean(epochs, want):
+    run = _run(EVENTS + [_sharded_epoch(e) for e in epochs])
+    assert read("shard_straggle.shard4", run) == pytest.approx(want)
+
+
+def test_epoch_fetches_is_the_mean_fetches_an_epoch():
+    one_chip = [dict(e, attrs=dict(e["attrs"], fetches=1))
+                for e in EVENTS if e["name"] == "race.epoch"]
+    assert read("epoch_fetches.open", _run(one_chip)) == 1.0
+    sharded = [_sharded_epoch([1.0, 2.0], fetches=2) for _ in range(3)]
+    assert read("epoch_fetches.shard4", _run(sharded)) == 2.0
+    assert read("epoch_fetches.shard4",
+                _run(one_chip + sharded)) == pytest.approx(9 / 6)
+
+
+@pytest.mark.parametrize("name", ["shard_straggle.shard4",
+                                  "epoch_fetches.shard4"])
+def test_shard_readers_without_their_attributes_are_none(name):
+    # EVENTS' epochs are one chip's: no split by shard, no fetch count
+    assert read(name, _run(EVENTS)) is None
+    assert read(name, _run([])) is None

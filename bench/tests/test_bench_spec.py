@@ -62,6 +62,22 @@ def test_cell_resolves_to_its_files(workload):
         assert m["moves"] in e2e
 
 
+@pytest.mark.parametrize("workload", CELLS)
+def test_cell_has_a_chip_for_each_shard(workload):
+    cell = spec.cell(BENCH, workload)
+    config = spec.config(BENCH, cell["config"])
+    spec.check_chips(cell, config)
+    assert spec.shards(config) <= cell["chips"]
+
+
+def test_a_cell_with_fewer_chips_than_shards_is_refused():
+    cell = {"name": "w", "config": "c", "chips": 1}
+    spec.check_chips(cell, {})
+    spec.check_chips(dict(cell, chips=4), {"shards": 4})
+    with pytest.raises(ValueError, match="fewer than the 4 shards"):
+        spec.check_chips(cell, {"shards": 4})
+
+
 def test_every_metric_moves_an_end_to_end_metric():
     e2e = {m["name"] for m in BENCH["end_to_end"]}
     for m in BENCH["per_layer"]:

@@ -75,3 +75,67 @@ def test_reduction_of_a_chip_trace():
     # of them (the first microseconds of each finish the launch before)
     assert 0.008 < idle["bench.plane_step"] <= 0.009
     assert sum(idle.values()) == pytest.approx(r["window_s"] - r["busy_s"])
+
+
+class _Ev:
+    def __init__(self, name, start, end):
+        self.name, self.start_ns, self.duration_ns = name, start, end - start
+
+
+class _Line:
+    def __init__(self, name, events):
+        self.name, self.events = name, events
+
+
+class _Plane:
+    def __init__(self, name, lines):
+        self.name, self.lines = name, lines
+
+
+class _Trace:
+    def __init__(self, planes):
+        self.planes = planes
+
+
+def _chip(n, ops):
+    return _Plane(f"/device:TPU:{n}", [_Line("XLA Ops", [
+        _Ev(f"%{name}.{i} = f32[2] fusion(x)", s, e)
+        for i, (name, s, e) in enumerate(ops)])])
+
+
+def test_reduction_over_four_chips(monkeypatch):
+    """Four chips, their planes out of order: busy time is the mean chip's,
+    operation time the sum over the chips, and idle gaps are chip 0's.
+    The readers then give the chips' mean idle share and the mean chip's
+    roofline share."""
+    from jax import profiler
+
+    from bench import spec
+    from bench.harness import Run
+    busy = {0: [("fused_epoch_pull", 0, 40)],
+            1: [("fused_epoch_pull", 10, 30), ("fusion", 60, 70)],
+            2: [("fused_epoch_pull", 0, 100)],
+            3: []}
+    host = _Plane("/host:CPU", [_Line("python", [
+        _Ev("bench.window", 0, 100), _Ev("repro.race.sync", 40, 80)])])
+    planes = [_chip(n, busy[n]) for n in (2, 0, 3, 1)] + [host]
+    monkeypatch.setattr(profiler.ProfileData, "from_file",
+                        lambda path: _Trace(planes))
+    r = tr.reduce_trace(DATA)
+    assert r["window_s"] == pytest.approx(100e-9)
+    assert r["busy_s"] == pytest.approx((40 + 30 + 100 + 0) / 4 * 1e-9)
+    assert r["op_s"]["fused_epoch_pull"] == pytest.approx(160e-9)
+    assert r["op_s"]["fusion"] == pytest.approx(10e-9)
+    # chip 0 is idle from 40 to 100: 40 under race.sync, 20 under nothing
+    assert dict(r["idle_gaps"]) == pytest.approx(
+        {"repro.race.sync": 40e-9, "host.other": 20e-9})
+    run = Run(workload="w", config={"itemsize": 4}, mix={}, seconds=1.0,
+              t0=0.0, t1=1.0, requests=[], setup_s=1.0, peak_bytes=1,
+              events=[{"name": "race.epoch",
+                       "attrs": {"coord_ops": 819e9 * 80e-9 / 4}}],
+              hist={}, trace=r, peaks={"hbm_bytes_per_s": 819e9})
+    idle = spec.reader(spec.reader_path("device_idle"))(run)
+    assert idle == pytest.approx(100 * (1 - 42.5 / 100))
+    roof = spec.reader(spec.reader_path("fused_epoch_pull_roofline.closed"))
+    # 80 ns of one chip's bandwidth over 160 ns summed over four chips
+    assert roof(run) == pytest.approx(50.0)

@@ -9,9 +9,15 @@
   ``call``) is left out: the operations it runs are events of their own
   inside it, and would count twice.
 * ``device_ops``: the ten operations that took most time.
-* ``idle_gaps``: the idle time of the first device, split by what the host
-  was doing meanwhile: the innermost ``bench.*`` or ``repro.*`` host
-  annotation open at that moment, or ``host.other``.
+* ``idle_gaps``: the idle time of the first device (``/device:TPU:0``, the
+  lowest number in the trace), split by what the host was doing meanwhile:
+  the innermost ``bench.*`` or ``repro.*`` host annotation open at that
+  moment, or ``host.other``.
+
+Over several chips: ``busy_s`` is the mean chip's, so ``1 - busy/window``
+is the chips' mean idle share; ``op_s`` sums each operation over the
+chips, so a roofline of the work's bytes at one chip's bandwidth over that
+sum is the mean chip's share; ``idle_gaps`` are the first chip's alone.
 """
 from __future__ import annotations
 
@@ -19,7 +25,7 @@ import glob
 import os
 import re
 
-DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 HOST_PREFIXES = ("bench.", "repro.")
 CONTAINERS = ("while", "conditional", "call")
@@ -110,16 +116,17 @@ def reduce_trace(path: str) -> dict:
     if os.path.isdir(path):
         path = find_xplane(path)
     pd = ProfileData.from_file(path)
-    devices, spans, window = [], [], None
+    devices, spans, window = {}, [], None
     for plane in pd.planes:
-        if DEVICE_PLANE.match(plane.name):
+        dev = DEVICE_PLANE.match(plane.name)
+        if dev:
             ops = []
             for line in plane.lines:
                 if line.name == OPS_LINE:
                     ops.extend((op_name(ev.name), ev.start_ns,
                                 ev.start_ns + ev.duration_ns)
                                for ev in line.events)
-            devices.append(ops)
+            devices[int(dev.group(1))] = ops
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
@@ -134,7 +141,7 @@ def reduce_trace(path: str) -> dict:
         raise ValueError(f"no TPU device plane in {path}")
     lo, hi = window
     busy_ns, op_ns, first_busy = [], {}, None
-    for ops in devices:
+    for _, ops in sorted(devices.items()):
         inside = [(n, max(s, lo), min(e, hi)) for n, s, e in ops
                   if e > lo and s < hi]
         merged = _union((s, e) for _, s, e in inside)
